@@ -13,6 +13,7 @@ from benchmarks import (compactness, composition, decompression, height,
                         iterations, merge_throughput, pipeline_breakdown,
                         pruning_bench, query_serving, roofline_report,
                         scalability, speed)
+from repro.launch.compile_cache import enable_compile_cache
 
 SUITES = {
     "compactness": compactness.run,     # Fig 5a / Fig 1a
@@ -37,6 +38,7 @@ def main(argv=None):
     ap.add_argument("--full", action="store_true")
     ap.add_argument("--only", default=None, help="comma-separated suite names")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     only = args.only.split(",") if args.only else list(SUITES)
     t0 = time.perf_counter()
     for name in only:

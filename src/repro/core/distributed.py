@@ -41,11 +41,6 @@ from repro.graphs.csr import Graph
 
 MAXU = jnp.uint32(0xFFFFFFFF)
 
-try:  # jax ≥ 0.4.38 re-exports shard_map at the top level
-    _shard_map = jax.shard_map
-except AttributeError:  # older jax: experimental location
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 
 def _hash_u32(x, a, b):
     h = x.astype(jnp.uint32) * jnp.uint32(a) + jnp.uint32(b)
@@ -83,7 +78,7 @@ def shingles_sharded(mesh, data_axes=("data",)):
     def fn(src, dst, n, a, b):
         h_self = _hash_u32(jnp.arange(n, dtype=jnp.uint32), a, b)
         edge_spec = P(data_axes if len(data_axes) > 1 else data_axes[0])
-        return _shard_map(
+        return jax.shard_map(
             functools.partial(_local, a=a, b=b),
             mesh=mesh,
             in_specs=(edge_spec, edge_spec, P(None)),

@@ -131,18 +131,20 @@ class SummarizerEngine:
 
     # ------------------------------------------------------------- plumbing
     def _mesh_active(self):
-        """Resolve the mesh for the multi-device dispatches (or None)."""
+        """Resolve the mesh for the multi-device dispatches (or None). An
+        explicit one-device mesh shards nothing: it selects the
+        single-device path (run context, adjacency bank) on that device."""
         if self.backend not in ("batched", "resident"):
             return None
         if self.mesh is not None:
-            return self.mesh
+            return self.mesh if self.mesh.size > 1 else None
         try:
             import jax
-            if jax.device_count() > 1:
-                from repro.launch.mesh import make_data_mesh
-                return make_data_mesh()
-        except Exception:  # jax unavailable/misconfigured: host path
+        except ImportError:  # jax not installed: host path
             return None
+        if jax.device_count() > 1:
+            from repro.launch.mesh import make_data_mesh
+            return make_data_mesh()
         return None
 
     def _setup_dispatches(self, g):
@@ -180,12 +182,9 @@ class SummarizerEngine:
             # DEVICE from its run context (edges uploaded once, root map
             # advanced by plan replay); the others use the NumPy twin.
             if self.backend == "resident":
-                try:
-                    from repro.core.resident import ResidentRunContext
-                    self._run_ctx = ResidentRunContext(g, bank=True)
-                    self._shingle_provider = self._run_ctx.for_roots
-                except Exception:  # jax unavailable: host twin, same bits
-                    self._run_ctx = None
+                from repro.core.resident import ResidentRunContext
+                self._run_ctx = ResidentRunContext(g, bank=True)
+                self._shingle_provider = self._run_ctx.for_roots
             if self._shingle_provider is None:
                 from repro.core.minhash import host_shingle_provider
                 self._shingle_provider = host_shingle_provider(g)
@@ -344,11 +343,11 @@ class SummarizerEngine:
         pg = as_partitioned(g, self.partitions)
         state = SluggerState(pg.to_graph())
         transfer0 = TRANSFER.snapshot()  # before setup: run-context init counts
+        deg_mark = faults.DEGRADATIONS.count()  # … and so does a bank refusal
         self._setup_dispatches(state.g)
         self.stats = {name: 0.0 for name in STAGE_ORDER}
         self.stats["merges"] = 0
         self.stats["checkpoint"] = 0.0
-        deg_mark = faults.DEGRADATIONS.count()
         transfer_prev = transfer0
         self.stats["transfer_iters"] = []
         ckpt = None

@@ -68,11 +68,14 @@ def _run_round_op(arena, site: str, build, args):
     """Run one compiled round op; a failed Pallas dispatch retries ONCE on
     the jnp `ref.py` twin (§11 degradation policy — bit-identical by the
     kernel twin contract), dropping ``use_kernel`` for the arena's life.
+    The op is lowered and compiled BEFORE the retry scope: a kernel the
+    compiler refuses fails the run, it is never hidden behind the twin.
     The retry is safe for injected faults because the dispatch wrappers in
     `kernels/*/ops.py` raise BEFORE the compiled call touches its donated
     buffers; a genuine mid-execution failure may have consumed them, in
     which case the retry surfaces that error instead of masking it."""
     fn = build(arena.use_kernel)
+    fn.compile(*args)
     try:
         return fn(*args)
     except Exception as e:
@@ -129,6 +132,7 @@ class ResidentBitmapArena:
         self._alive = self._put(alive_p)
         counter.add_h2d(bits_p.nbytes + alive_p.nbytes, phase="upload")
         self.rounds = 0
+        self._K = 0            # padded dirty-row count of the round op
         self.Rp = 0            # set by attach_counts
         self._counts = None    # v2 resident count state, or None (v1 mode)
 
@@ -237,6 +241,7 @@ class ResidentBitmapArena:
             bank._hgt, res_map, jnp.asarray(members), jnp.asarray(ptr),
             jnp.asarray(lens))
         arena.rounds = 0
+        arena._K = 0
         arena._counts = True
         return arena
 
@@ -349,11 +354,13 @@ class ResidentBitmapArena:
         if self._counts is None:
             raise RuntimeError("propose_rows needs attach_counts state")
         n = rb.size
-        K = pow2(n, floor=64)
+        # the dirty queue only shrinks, so the first round's padded count
+        # holds for the arena's life: one compiled round op per chunk
+        self._K = max(self._K, pow2(n, floor=64))
 
         def build(uk):
-            return round_fn(self.Bp, self.G, self.Rp, self.Wp, K, self.J,
-                            self.J, height_bound=height_bound,
+            return round_fn(self.Bp, self.G, self.Rp, self.Wp, self._K,
+                            self.J, self.J, height_bound=height_bound,
                             use_kernel=uk, interpret=self.interpret,
                             mesh=self.mesh, axes=self.axes)
         self.counter.add_h2d(4, phase="rank")  # the θ̂ scalar
@@ -387,7 +394,9 @@ class ResidentBitmapArena:
         starts = np.flatnonzero(head)
         counts = np.diff(np.concatenate([starts, [m]]))
         slot = np.arange(m) - np.repeat(starts, counts)
-        P_pairs = min(pow2(int(counts.max()), floor=2), max(self.G // 2, 1))
+        # a matching holds at most G // 2 pairs per group; padding every
+        # round to that keeps one compiled fold op per chunk
+        P_pairs = max(self.G // 2, 1)
         instr = np.zeros((self.Bp, P_pairs, 3), dtype=np.int32)
         instr[b, slot, 0] = a
         instr[b, slot, 1] = z
@@ -633,7 +642,8 @@ class ResidentRunContext:
     bank-advance slab already names (A, Z, M), so ``res_map`` composes
     inside the same donated device call. If the bank's exactness guard
     declines the graph (`OverflowError` at seed time), ``bank`` stays
-    ``None`` and the engine falls back to the host-rebuilt upload path.
+    ``None``, the engine falls back to the host-rebuilt upload path, and
+    the fallback is recorded in `faults.DEGRADATIONS`.
     """
 
     def __init__(self, g, *, counter=TRANSFER, bank: bool = False,
@@ -655,10 +665,13 @@ class ResidentRunContext:
             try:
                 self.bank = ResidentAdjacencyBank(
                     g, counter=counter, min_capacity=bank_min_capacity)
-            except OverflowError:
+            except OverflowError as e:
                 # exactness guard tripped — stay on the host-rebuilt path
-                # (its per-chunk `_fill` guards re-check at runtime)
-                self.bank = None
+                # (its per-chunk `_fill` guards re-check at runtime), and
+                # count it: the run is no longer the bank path
+                faults.DEGRADATIONS.record("resident.bank.init", e)
+                log.warning("adjacency bank declined the graph; using the "
+                            "host-rebuilt upload path: %r", e)
 
     # ------------------------------------------------------- plan replay
     def advance(self, batches: list):

@@ -32,8 +32,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.bitset_jaccard.ref import popcount_u32 as _swar_popcount
-
 _KEY_BITS = 15
 
 # Integer-exact Saving contract (DESIGN.md §9). All backends clamp the
@@ -46,11 +44,8 @@ C_CLAMP = 1 << 30
 # on host int64 and device uint32 limbs. θ = 0 → P = 0 accepts Saving ≥ 0.
 THETA_SHIFT = 20
 
-if hasattr(jnp, "bitwise_count"):  # native popcnt lowering (jax ≥ 0.4.27)
-    def popcount_u32(x):
-        return jnp.bitwise_count(x).astype(jnp.int32)
-else:  # pragma: no cover - old jax
-    popcount_u32 = _swar_popcount  # quantized keys live in [0, 2^15]; (key+1)*G fits int32
+def popcount_u32(x):
+    return jnp.bitwise_count(x).astype(jnp.int32)
 
 
 def bit_length(v):

@@ -52,21 +52,12 @@ def mesh_content_key(mesh):
 
 
 def shard_map_no_check(fn, mesh, in_specs, out_specs):
-    """shard_map with the replication check disabled (pallas_call has no
-    replication rule), papering over two jax API drifts: the top-level vs
-    experimental import and the check_rep → check_vma kwarg rename."""
+    """shard_map with the varying-axes check disabled (pallas_call has no
+    replication rule)."""
     import jax
 
-    try:  # jax ≥ 0.4.38 re-exports shard_map at the top level
-        sm = jax.shard_map
-    except AttributeError:  # older jax: experimental location
-        from jax.experimental.shard_map import shard_map as sm
-    try:
-        return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False)
-    except TypeError:  # newer jax renamed the kwarg
-        return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=False)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 class LruCache:

@@ -15,6 +15,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.common import default_interpret
+
 _SENTINEL = np.uint32(0xFFFFFFFF)
 _MAX_HASH = np.uint32(0xFFFFFFFF)
 
@@ -41,8 +43,14 @@ def _minhash_block(nbr_ref, out_ref, *, a: int, b: int, w_total: int):
 
 def rowmin_hash_kernel(nbr: jax.Array, a: int, b: int,
                        block_r: int = 256, block_w: int = 128,
-                       interpret: bool = True) -> jax.Array:
-    """(R, W) uint32 padded adjacency -> (R,) uint32 shingle values."""
+                       interpret=None) -> jax.Array:
+    """(R, W) uint32 padded adjacency -> (R,) uint32 shingle values.
+
+    Does not compile for TPU (its 1-D output block breaks the tiling
+    rule); ``interpret`` defaults to interpreting off-TPU only, so a call
+    on the chip fails instead of silently interpreting."""
+    if interpret is None:
+        interpret = default_interpret()
     R, W = nbr.shape
     br = min(block_r, R)
     bw = min(block_w, W)

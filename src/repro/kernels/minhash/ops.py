@@ -36,7 +36,7 @@ def pack_adjacency(indptr: np.ndarray, indices: np.ndarray, width: int = 128):
 
 def node_shingles(nbr_rows: jax.Array, row_owner: np.ndarray, n: int,
                   a: int, b: int, use_kernel: bool = True,
-                  interpret: bool = True) -> jax.Array:
+                  interpret=None) -> jax.Array:
     """Per-node shingle = min hash over N(u) ∪ {u}."""
     if use_kernel:
         mins = rowmin_hash_kernel(nbr_rows, a, b, interpret=interpret)

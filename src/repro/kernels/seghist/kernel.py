@@ -21,6 +21,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.common import default_interpret
+
 
 def _seghist_block(seg_ref, out_ref, *, block_s: int):
     j = pl.program_id(0)  # segment block
@@ -38,8 +40,14 @@ def _seghist_block(seg_ref, out_ref, *, block_s: int):
 
 def segment_histogram_kernel(seg: jax.Array, num_segments: int,
                              block_s: int = 512, block_e: int = 1024,
-                             interpret: bool = True) -> jax.Array:
-    """seg: (E,) int32 ids in [0, num_segments) or -1 -> (num_segments,) int32."""
+                             interpret=None) -> jax.Array:
+    """seg: (E,) int32 ids in [0, num_segments) or -1 -> (num_segments,) int32.
+
+    Does not compile for TPU yet; ``interpret`` defaults to interpreting
+    off-TPU only, so a call on the chip fails instead of silently
+    interpreting."""
+    if interpret is None:
+        interpret = default_interpret()
     E = seg.shape[0]
     S = int(num_segments)
     bs = min(block_s, max(S, 1))

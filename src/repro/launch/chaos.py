@@ -26,6 +26,7 @@ import numpy as np
 from repro import faults
 from repro.core.engine import STAGE_ORDER, SummarizerEngine
 from repro.graphs import generators
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def _engine(backend: str = "numpy", partitions: int = 1,
@@ -98,6 +99,7 @@ def main(argv=None):
     ap.add_argument("--kill-at", type=int, default=3,
                     help="iteration the stage-boundary faults fire in")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     if args.kernel_fault:
         return run_kernel_fault()
     return run_stage_kills(T=args.iters, kill_at=args.kill_at)

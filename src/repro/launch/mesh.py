@@ -9,13 +9,9 @@ import jax
 
 
 def _make_mesh(shape, axes):
-    """jax.make_mesh with Auto axis types; older jax has no AxisType (its
-    meshes are Auto by default)."""
-    try:
-        kinds = (jax.sharding.AxisType.Auto,) * len(axes)
-    except AttributeError:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes, axis_types=kinds)
+    """jax.make_mesh with Auto axis types."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

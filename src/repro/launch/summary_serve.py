@@ -25,6 +25,7 @@ from repro.core.query_batch import (BACKENDS, edge_exists_batch,
 from repro.core.slugger import summarize
 from repro.core.summary_ir import PackedSummary
 from repro.graphs.generators import SERVING_GRAPHS
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.serve import RequestError, pad_to_slots
 
 
@@ -141,6 +142,7 @@ def main(argv=None):
                     help="write the packed .npz here and serve from the reload")
     ap.add_argument("--iters", type=int, default=5, help="merge iterations")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     name = "smoke" if args.smoke else args.edges
     g = SERVING_GRAPHS[name]()
