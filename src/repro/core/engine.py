@@ -38,7 +38,6 @@ from __future__ import annotations
 
 import logging
 import os
-import time
 from concurrent.futures import ThreadPoolExecutor
 
 import numpy as np
@@ -48,11 +47,30 @@ from repro.core.merging import apply_plans, build_merge_work
 from repro.core.minhash import candidate_groups
 from repro.core.pruning import prune
 from repro.core.slugger import SluggerState, _emit_encoding
+from repro.core.spans import GLOBAL as SPANS, span
 from repro.graphs.partitioned import PartitionedGraph, as_partitioned
 
 log = logging.getLogger("repro.engine")
 
 STAGE_ORDER = ("shingle", "group", "pack", "merge_round", "exchange")
+
+# flat float entries of ``SummarizerEngine.stats`` read from one job's spans
+# (`core/spans.py`): stats key -> (span name, field of its totals)
+SPAN_STATS = {
+    **{name: (f"stage.{name}", "wall") for name in STAGE_ORDER},
+    "setup": ("setup", "wall"),
+    "checkpoint": ("checkpoint", "wall"),
+    "exchange.replay": ("exchange.replay", "wall"),
+    "exchange.bank_advance": ("exchange.bank_advance", "wall"),
+    "merge.host_sweep": ("merge.host_sweep", "wall"),
+    "merge.extract": ("merge.extract", "wall"),
+    "merge.round": ("merge.round", "wall"),
+    "merge.fold": ("merge.fold", "wall"),
+    "merge.chunk.self": ("merge.chunk", "self"),
+    "merge.thunk": ("merge.thunk", "wall"),
+    "merge.thunk.max": ("merge.thunk", "max"),
+    "merge.thunk.cpu": ("merge.thunk", "cpu"),
+}
 
 
 class IterationContext:
@@ -243,7 +261,8 @@ class SummarizerEngine:
 
     def stage_merge_round(self, ctx: IterationContext):
         """Run the shard-local sweeps — serial or thread-parallel; record
-        mode makes the schedule irrelevant to the outcome."""
+        mode makes the schedule irrelevant to the outcome. Each thunk opens
+        its own ``merge.thunk`` span (`merging.build_merge_work`)."""
         if self.workers > 1 and len(ctx.thunks) > 1:
             with ThreadPoolExecutor(max_workers=self.workers) as pool:
                 list(pool.map(lambda f: f(), ctx.thunks))
@@ -270,16 +289,19 @@ class SummarizerEngine:
             batches: list = []
             # row_len[M] is pristine exactly at the on_batch hook — the bank
             # carry needs the minted rows' unique-external counts
-            merges = apply_plans(
-                state, plans,
-                on_batch=lambda A, Z, M: batches.append(
-                    (A, Z, M, state.row_len[M].copy())))
+            with span("exchange.replay"):
+                merges = apply_plans(
+                    state, plans,
+                    on_batch=lambda A, Z, M: batches.append(
+                        (A, Z, M, state.row_len[M].copy())))
             try:
-                self._run_ctx.advance(batches)
+                with span("exchange.bank_advance"):
+                    self._run_ctx.advance(batches)
             except Exception as e:
                 self._degrade_to_host(state, "resident.bank.advance", e)
             return merges
-        return apply_plans(state, plans)
+        with span("exchange.replay"):
+            return apply_plans(state, plans)
 
     def _degrade_to_host(self, state, site: str, exc) -> None:
         """§11 degradation policy: drop the resident run context (bank,
@@ -329,8 +351,10 @@ class SummarizerEngine:
                      checkpoint_every: int = 1):
         """Run the T merge iterations only; returns ``(state, pg)`` — the
         merge-forest state and the partitioned graph. Per-stage wall
-        seconds land in ``self.stats``; the partition-sweep benchmark
-        reads the merge phase from there.
+        seconds, and the seconds of the spans inside them (`SPAN_STATS`),
+        land in ``self.stats``, read from this run's spans; the
+        partition-sweep benchmark reads the merge phase from there. The
+        count of each span is in ``self.stats["span_counts"]``.
 
         With ``checkpoint_dir`` set, the iteration's applied plan log is
         committed atomically after every ``checkpoint_every``-th iteration
@@ -340,16 +364,16 @@ class SummarizerEngine:
         backend and partition count (DESIGN.md §11)."""
         from repro.core.transfer import GLOBAL as TRANSFER
 
-        pg = as_partitioned(g, self.partitions)
-        state = SluggerState(pg.to_graph())
-        transfer0 = TRANSFER.snapshot()  # before setup: run-context init counts
-        deg_mark = faults.DEGRADATIONS.count()  # … and so does a bank refusal
-        self._setup_dispatches(state.g)
-        self.stats = {name: 0.0 for name in STAGE_ORDER}
-        self.stats["merges"] = 0
-        self.stats["checkpoint"] = 0.0
+        spans0 = SPANS.snapshot()
+        with span("setup"):
+            pg = as_partitioned(g, self.partitions)
+            state = SluggerState(pg.to_graph())
+            transfer0 = TRANSFER.snapshot()  # run-context init counts
+            deg_mark = faults.DEGRADATIONS.count()  # … and a bank refusal
+            self._setup_dispatches(state.g)
+        self.stats = {"merges": 0, "transfer_iters": []}
         transfer_prev = transfer0
-        self.stats["transfer_iters"] = []
+        spans_prev = SPANS.snapshot()
         ckpt = None
         fingerprint = None
         plan_log: list = []
@@ -363,11 +387,10 @@ class SummarizerEngine:
                 loaded = ckpt.load_latest(fingerprint, self._config())
                 if loaded is not None:
                     t_done, plan_log = loaded
-                    t0 = time.perf_counter()
-                    for plans in plan_log:
-                        self.stats["merges"] += self._replay_plans(state,
-                                                                   plans)
-                    self.stats["exchange"] += time.perf_counter() - t0
+                    with span("stage.exchange"):
+                        for plans in plan_log:
+                            self.stats["merges"] += self._replay_plans(
+                                state, plans)
                     t_start = t_done + 1
                     self.stats["resumed_from"] = t_done
                     log.info("resumed from checkpoint at iter %d (%d plans "
@@ -379,39 +402,48 @@ class SummarizerEngine:
             ctx = IterationContext(t, theta, state, pg)
             ctx.ss_groups, ctx.ss_merge = iter_streams[t - 1].spawn(2)
             for name in STAGE_ORDER:
-                t0 = time.perf_counter()
-                try:
-                    self.stages[name](self, ctx)
-                except faults.BankFault as e:
-                    # bank extraction died mid-stage: plans/thunks built
-                    # against the bank are shells — degrade, then rebuild
-                    # pack onward against the same iteration-start snapshot
-                    # and spawned streams (pure functions → identical
-                    # decisions, DESIGN.md §11)
-                    self._degrade_to_host(ctx.state,
-                                          "resident.bank.extract", e)
-                    self.stages["pack"](self, ctx)
-                    if name == "merge_round":
-                        self.stages["merge_round"](self, ctx)
-                self.stats[name] += time.perf_counter() - t0
+                with span(f"stage.{name}"):
+                    try:
+                        self.stages[name](self, ctx)
+                    except faults.BankFault as e:
+                        # bank extraction died mid-stage: plans/thunks
+                        # built against the bank are shells — degrade, then
+                        # rebuild pack onward against the same
+                        # iteration-start snapshot and spawned streams (pure
+                        # functions → identical decisions, DESIGN.md §11)
+                        self._degrade_to_host(ctx.state,
+                                              "resident.bank.extract", e)
+                        self.stages["pack"](self, ctx)
+                        if name == "merge_round":
+                            self.stages["merge_round"](self, ctx)
                 faults.check(f"engine.{name}", iteration=t)
             self.stats["merges"] += ctx.merges
             if ckpt is not None:
                 plan_log.append(ctx.plans)
                 if t % max(1, checkpoint_every) == 0 or t == self.T:
-                    t0 = time.perf_counter()
-                    ckpt.save(t, plan_log, fingerprint, self._config())
-                    self.stats["checkpoint"] += time.perf_counter() - t0
+                    with span("checkpoint"):
+                        ckpt.save(t, plan_log, fingerprint, self._config())
             snap = TRANSFER.snapshot()
-            self.stats["transfer_iters"].append(
-                TRANSFER.delta_since(transfer_prev, now=snap))
+            it_transfer = TRANSFER.delta_since(transfer_prev, now=snap)
+            self.stats["transfer_iters"].append(it_transfer)
             transfer_prev = snap
+            it_spans = SPANS.delta_since(spans_prev)
+            spans_prev = SPANS.snapshot()
             log.info(
-                "iter %3d: θ=%.3f groups=%d merges=%d roots=%d parts=%d",
+                "iter %3d: θ=%.3f groups=%d merges=%d roots=%d parts=%d "
+                "host_sweeps=%d chunks=%d rounds=%d",
                 t, theta, len(ctx.groups), ctx.merges, state.alive.size,
-                self.partitions)
+                self.partitions,
+                it_spans.get("merge.host_sweep", {}).get("count", 0),
+                it_spans.get("merge.chunk", {}).get("count", 0),
+                it_transfer["rounds"])
         self.stats["transfer"] = TRANSFER.delta_since(transfer0)
         self.stats["degradations"] = faults.DEGRADATIONS.count() - deg_mark
+        spans = SPANS.delta_since(spans0)
+        for key, (name, field) in SPAN_STATS.items():
+            self.stats[key] = float(spans.get(name, {}).get(field, 0.0))
+        self.stats["span_counts"] = {name: d["count"]
+                                     for name, d in spans.items()}
         return state, pg
 
     def run(self, g, checkpoint_dir=None, resume: bool = False,
@@ -421,12 +453,12 @@ class SummarizerEngine:
                                       resume=resume,
                                       checkpoint_every=checkpoint_every)
         owner = pg.owner if self.partitions > 1 else None
-        t0 = time.perf_counter()
-        summary = _emit_encoding(state, backend=self.backend, owner=owner)
-        self.stats["emit"] = time.perf_counter() - t0
+        with span("emit") as sp:
+            summary = _emit_encoding(state, backend=self.backend, owner=owner)
+        self.stats["emit"] = sp.wall
         if self.prune_steps:
-            t0 = time.perf_counter()
-            summary = prune(summary, steps=self.prune_steps,
-                            partition_map=owner)
-            self.stats["prune"] = time.perf_counter() - t0
+            with span("prune") as sp:
+                summary = prune(summary, steps=self.prune_steps,
+                                partition_map=owner)
+            self.stats["prune"] = sp.wall
         return summary

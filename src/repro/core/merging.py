@@ -30,6 +30,7 @@ import logging
 import numpy as np
 
 from repro.core.bitops import popcount
+from repro.core.spans import span
 
 
 def _pair_cost(cnt, poss):
@@ -554,7 +555,8 @@ class ResidentRankSource:
         return self.arena.propose_rows(rb, rr, j_max, theta_p, height_bound)
 
     def on_merges(self, ws, b, a, z):
-        self.arena.fold_counts(b, a, z)
+        with span("merge.fold"):
+            self.arena.fold_counts(b, a, z)
 
 
 class BatchedGroupWorkspace:
@@ -1037,22 +1039,35 @@ def build_merge_work(
 
                 def factory(w):
                     return ResidentBitmapArena.from_workspace(w, top_j=top_j)
-            return ResidentRankSource(factory(ws))
+            # the arena's build: extraction from the device bank, or the
+            # upload of a host-packed chunk
+            with span("merge.extract"):
+                arena = factory(ws)
+            return ResidentRankSource(arena)
         if backend == "batched":
             dispatch = rank_dispatch or _default_intersections_dispatch()
             return HostRankSource(dispatch)
         return HostRankSource(None)
 
+    # every thunk is one ``merge.thunk`` span; inside it a host sweep of an
+    # oversized group is ``merge.host_sweep``, and a batched chunk is
+    # ``merge.chunk``, whose self time is the host's per-round matching and
+    # bookkeeping (its arena build ``merge.extract``, round trips
+    # ``merge.round`` and folds ``merge.fold`` are spans of their own)
     def _seq_thunk(ws, rng):
-        return lambda: _sweep_sequential(ws, theta, rng, top_j=top_j,
+        def run():
+            with span("merge.thunk"), span("merge.host_sweep"):
+                return _sweep_sequential(ws, theta, rng, top_j=top_j,
                                          height_bound=height_bound)
+        return run
 
     def _batch_thunk(ws):
         def run():
             # the ranker is built at RUN time: the resident arena's one-time
             # bitmap upload belongs to the merge_round stage, not pack
-            return ws.sweep(theta, _make_ranker(ws), top_j=top_j,
-                            height_bound=height_bound)
+            with span("merge.thunk"), span("merge.chunk"):
+                return ws.sweep(theta, _make_ranker(ws), top_j=top_j,
+                                height_bound=height_bound)
         return run
 
     buckets: dict = {}

@@ -49,6 +49,7 @@ import logging
 import numpy as np
 
 from repro import faults
+from repro.core.spans import span
 from repro.core.transfer import GLOBAL as TRANSFER
 
 log = logging.getLogger("repro.engine")
@@ -364,10 +365,13 @@ class ResidentBitmapArena:
                             use_kernel=uk, interpret=self.interpret,
                             mesh=self.mesh, axes=self.axes)
         self.counter.add_h2d(4, phase="rank")  # the θ̂ scalar
-        self._dirty, out = _run_round_op(
-            self, "kernel.bitset_fold.round", build,
-            self._state() + (jnp.uint32(theta_p),))
-        out = np.asarray(out)
+        # the device round trip: dispatch, any queue ahead of it, the op,
+        # and the verdicts' download
+        with span("merge.round"):
+            self._dirty, out = _run_round_op(
+                self, "kernel.bitset_fold.round", build,
+                self._state() + (jnp.uint32(theta_p),))
+            out = np.asarray(out)
         self.counter.add_d2h(out.nbytes, phase="rank")
         self.counter.tick_round()
         self.rounds += 1

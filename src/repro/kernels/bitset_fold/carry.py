@@ -57,7 +57,7 @@ def advance_fn(cap: int, mp: int):
         return fn
 
     @functools.partial(jax.jit, donate_argnums=(0,))
-    def fn(res_map, tri):
+    def root_map_advance(res_map, tri):
         fwd = jnp.arange(cap, dtype=jnp.int32)
         fwd = fwd.at[tri[0]].set(tri[2], mode="drop")
         fwd = fwd.at[tri[1]].set(tri[2], mode="drop")
@@ -65,8 +65,8 @@ def advance_fn(cap: int, mp: int):
             fwd = fwd[fwd]
         return fwd[res_map]
 
-    _ADVANCE_CACHE[key] = fn
-    return fn
+    _ADVANCE_CACHE[key] = root_map_advance
+    return root_map_advance
 
 
 def bank_advance_fn(cap: int, E: int, Pp: int, Tp: int):
@@ -84,12 +84,12 @@ def bank_advance_fn(cap: int, E: int, Pp: int, Tp: int):
         return fn
 
     @functools.partial(jax.jit, donate_argnums=(0, 1, 2, 3, 4, 5, 6))
-    def fn(gids, cnts, size, selfc, nd, hgt, res_map, slab):
+    def bank_advance(gids, cnts, size, selfc, nd, hgt, res_map, slab):
         return _ref.bank_advance(gids, cnts, size, selfc, nd, hgt, res_map,
                                  slab, Tp)
 
-    _BANK_ADVANCE_CACHE[key] = fn
-    return fn
+    _BANK_ADVANCE_CACHE[key] = bank_advance
+    return bank_advance
 
 
 def bank_grow_fn(E: int, newE: int):
@@ -106,13 +106,13 @@ def bank_grow_fn(E: int, newE: int):
         return fn
 
     @jax.jit
-    def fn(gids, cnts):
+    def bank_grow(gids, cnts):
         g = jnp.zeros(newE, dtype=jnp.int32).at[:E].set(gids)
         c = jnp.zeros(newE, dtype=jnp.int32).at[:E].set(cnts)
         return g, c
 
-    _BANK_GROW_CACHE[key] = fn
-    return fn
+    _BANK_GROW_CACHE[key] = bank_grow
+    return bank_grow
 
 
 def shingle_roots_fn(n: int, cap: int, m_edges: int):
@@ -130,7 +130,7 @@ def shingle_roots_fn(n: int, cap: int, m_edges: int):
         return fn
 
     @jax.jit
-    def fn(src, dst, res_map, a, b):
+    def shingle_roots(src, dst, res_map, a, b):
         h_self = _hash_u32(jnp.arange(n, dtype=jnp.uint32), a, b)
         seg = jax.ops.segment_min(_hash_u32(dst, a, b), src, num_segments=n)
         node_sh = jnp.minimum(h_self, seg)
@@ -140,5 +140,5 @@ def shingle_roots_fn(n: int, cap: int, m_edges: int):
                                   num_segments=cap)
         return sh, cnt
 
-    _SHINGLE_CACHE[key] = fn
-    return fn
+    _SHINGLE_CACHE[key] = shingle_roots
+    return shingle_roots

@@ -114,6 +114,7 @@ def jaccard_topj_kernel(bits: jax.Array, alive: jax.Array, J: int,
         out_shape=jax.ShapeDtypeStruct((G, J), jnp.int32),
         scratch_shapes=[pltpu.VMEM((G, G), jnp.int32)],
         interpret=interpret,
+        name="jaccard_topj",
     )(alive_row, words)
 
 
@@ -191,6 +192,7 @@ def bitset_fold_kernel(bits: jax.Array, alive: jax.Array, instr: jax.Array,
         ],
         input_output_aliases={1: 0, 2: 1},
         interpret=interpret,
+        name="bitset_fold",
     )(instr.astype(jnp.int32), _i32(bits), alive.astype(jnp.int32))
     return (jax.lax.bitcast_convert_type(nb, jnp.uint32),
             na.astype(alive.dtype))

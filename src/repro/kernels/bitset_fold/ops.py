@@ -99,17 +99,17 @@ def topj_fn(B: int, G: int, W: int, J: int, n_pad: int, *, use_kernel: bool,
                   else all_topj)
 
         @jax.jit
-        def fn(bits, alive, rows):
+        def bitset_topj(bits, alive, rows):
             t = ranked(bits, alive)                # (B, G, J) int32
             return t[rows[:, 0], rows[:, 1]].astype(jnp.int8)
     else:
         # single-device jnp twin: compute the selected rows only — integer-
         # identical to the gather above, O(n·G·W) instead of O(B·G²·W)
         @jax.jit
-        def fn(bits, alive, rows):
+        def bitset_topj(bits, alive, rows):
             return ref.topj_rows(bits, alive, rows, J).astype(jnp.int8)
 
-    fn = _Dispatch("kernel.bitset_fold.topj", fn)
+    fn = _Dispatch("kernel.bitset_fold.topj", bitset_topj)
     _TOPJ_CACHE[key] = fn
     return fn
 
@@ -183,8 +183,8 @@ def round_fn(B: int, G: int, R: int, W: int, K: int, J: int, top_j: int, *,
         sharded = _shard(all_round, mesh, axes, 11, 1)
 
         @functools.partial(jax.jit, donate_argnums=(2,))
-        def fn(bits, alive, dirty, CNT, colsize, memcol, s, selfc, nd, hgt,
-               cost, theta_p):
+        def bitset_round(bits, alive, dirty, CNT, colsize, memcol, s, selfc,
+                         nd, hgt, cost, theta_p):
             res = sharded(bits, alive, dirty, CNT, colsize, memcol, s,
                           selfc, nd, hgt, cost)                 # (B, G, 4)
             ok = (res[..., 0] > 0) & ref.theta_accept(
@@ -196,8 +196,8 @@ def round_fn(B: int, G: int, R: int, W: int, K: int, J: int, top_j: int, *,
             return ok.astype(dirty.dtype), out
     else:
         @functools.partial(jax.jit, donate_argnums=(2,))
-        def fn(bits, alive, dirty, CNT, colsize, memcol, s, selfc, nd, hgt,
-               cost, theta_p):
+        def bitset_round(bits, alive, dirty, CNT, colsize, memcol, s, selfc,
+                         nd, hgt, cost, theta_p):
             rb, rr = jnp.nonzero(dirty > 0, size=K, fill_value=(B, 0))
             rows = jnp.stack([rb.astype(jnp.int32),
                               rr.astype(jnp.int32)], axis=1)
@@ -222,7 +222,7 @@ def round_fn(B: int, G: int, R: int, W: int, K: int, J: int, top_j: int, *,
                 ok.astype(dirty.dtype), mode="drop")
             return dirty, out
 
-    fn = _Dispatch("kernel.bitset_fold.round", fn)
+    fn = _Dispatch("kernel.bitset_fold.round", bitset_round)
     _ROUND_CACHE[key] = fn
     return fn
 
@@ -247,13 +247,14 @@ def extract_fn(Bp: int, G: int, Rp: int, Wp: int, Lp: int, cap: int,
     per_b = functools.partial(ref.bank_extract_group, Rp=Rp, Wp=Wp, Lp=Lp)
 
     @jax.jit
-    def fn(gids, cnts, size, selfc, nd, hgt, res_map, members, ptr, lens):
+    def bank_extract(gids, cnts, size, selfc, nd, hgt, res_map, members, ptr,
+                     lens):
         return jax.vmap(per_b,
                         in_axes=(None, None, None, None, None, None, None,
                                  0, 0, 0))(gids, cnts, size, selfc, nd,
                                            hgt, res_map, members, ptr, lens)
 
-    fn = _Dispatch("kernel.bitset_fold.extract", fn)
+    fn = _Dispatch("kernel.bitset_fold.extract", bank_extract)
     _EXTRACT_CACHE[key] = fn
     return fn
 
@@ -297,8 +298,12 @@ def fold_counts_fn(B: int, G: int, R: int, W: int, P_pairs: int, *,
         one = ref.fold_pairs_counts
     v = jax.vmap(one)
     folded = _shard(v, mesh, axes, 12, 10) if mesh is not None else v
+
+    def bitset_fold_counts(*state_and_instr):
+        return folded(*state_and_instr)
+
     fn = _Dispatch("kernel.bitset_fold.fold_counts",
-                   jax.jit(folded, donate_argnums=(0, 1, 2, 3, 4, 6, 7, 8,
-                                                   9, 10)))
+                   jax.jit(bitset_fold_counts,
+                           donate_argnums=(0, 1, 2, 3, 4, 6, 7, 8, 9, 10)))
     _FOLDC_CACHE[key] = fn
     return fn

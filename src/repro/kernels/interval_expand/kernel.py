@@ -90,5 +90,6 @@ def interval_count_kernel(lo: jax.Array, hi: jax.Array, sign: jax.Array,
         out_specs=pl.BlockSpec((_ROWS, bp), lambda b, j, k: (b, j)),
         out_shape=jax.ShapeDtypeStruct((Bp, Pp), jnp.int32),
         interpret=interpret,
+        name="interval_count",
     )(lo2, hi2, sg2, pos2)
     return out[:B, :P]

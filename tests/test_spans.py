@@ -1,0 +1,212 @@
+"""Spans inside the summarizer (`core/spans.py`): the totals under threads,
+self time of nested spans, snapshot and delta, what one resident job
+reports in ``engine.stats``, and the spans landing in a profiler trace
+without changing the summary."""
+from __future__ import annotations
+
+import sys
+import threading
+import time
+from pathlib import Path
+
+import numpy as np
+import pytest
+
+from repro.core.engine import SPAN_STATS, STAGE_ORDER, SummarizerEngine
+from repro.core.merging import _BATCH_MAX_GROUP
+from repro.core.spans import SpanTotals, span
+from repro.graphs import generators as GG
+from repro.graphs.csr import Graph
+
+HUB_LEAVES = 200
+
+
+def _busy(seconds: float) -> None:
+    t0 = time.perf_counter()
+    while time.perf_counter() - t0 < seconds:
+        pass
+
+
+def test_totals_lose_no_count_under_threads():
+    totals = SpanTotals()
+    n_threads, per_thread = 8, 2000
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        def work():
+            for _ in range(per_thread):
+                with span("outer", totals):
+                    with span("inner", totals):
+                        pass
+
+        threads = [threading.Thread(target=work) for _ in range(n_threads)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+        assert not any(t.is_alive() for t in threads)
+    finally:
+        sys.setswitchinterval(old)
+    snap = totals.snapshot()
+    assert snap["outer"]["count"] == snap["inner"]["count"] \
+        == n_threads * per_thread
+    # each thread's inner spans are its outer spans' children, never
+    # another thread's
+    assert snap["outer"]["self"] == pytest.approx(
+        snap["outer"]["wall"] - snap["inner"]["wall"], rel=1e-9, abs=1e-9)
+
+
+def test_self_time_of_nested_spans():
+    totals = SpanTotals()
+    with span("parent", totals) as parent:
+        _busy(0.02)
+        with span("child", totals) as c1:
+            _busy(0.03)
+        with span("child", totals) as c2:
+            _busy(0.01)
+    snap = totals.snapshot()
+    assert snap["parent"]["wall"] == parent.wall
+    assert snap["child"]["wall"] == pytest.approx(c1.wall + c2.wall)
+    assert snap["parent"]["self"] == pytest.approx(
+        parent.wall - c1.wall - c2.wall)
+    assert snap["parent"]["self"] >= 0.02
+    assert snap["child"]["self"] == pytest.approx(snap["child"]["wall"])
+    assert snap["child"]["max"] == max(c1.wall, c2.wall)
+    # one thread's CPU seconds, never more than its wall seconds
+    assert 0.0 < snap["parent"]["cpu"] <= snap["parent"]["wall"] + 0.01
+
+
+def test_snapshot_and_delta_since():
+    totals = SpanTotals()
+    for seconds in (0.004, 0.012, 0.008):
+        totals.add("a", seconds, seconds / 2, seconds)
+    snap = totals.snapshot()
+    assert snap["a"]["count"] == 3
+    assert snap["a"]["max"] == 0.012
+    assert totals.delta_since(snap) == {}
+    # the longest span after the snapshot is shorter than the one before
+    for seconds in (0.002, 0.006, 0.001):
+        totals.add("a", seconds, 0.0, seconds)
+    totals.add("b", 0.5, 0.25, 0.5)
+    d = totals.delta_since(snap)
+    assert d["a"]["count"] == 3
+    assert d["a"]["wall"] == pytest.approx(0.009)
+    assert d["a"]["cpu"] == pytest.approx(0.0)
+    assert d["a"]["max"] == 0.006
+    assert d["b"] == {"count": 1, "wall": 0.5, "cpu": 0.25, "self": 0.5,
+                      "max": 0.5}
+    assert totals.snapshot()["a"]["max"] == 0.012
+    assert totals.delta_since({})["a"]["count"] == 6
+
+
+def _hub_graph() -> Graph:
+    """Caveman cliques plus one hub with degree-one leaves: the leaves
+    share a shingle, so one candidate group is over 128 members and is
+    swept on the host; the cliques fill batched chunks."""
+    g0 = GG.caveman(30, 6, 0.05, seed=3)
+    src = np.repeat(np.arange(g0.n), np.diff(g0.indptr))
+    keep = src < g0.indices
+    hub = g0.n
+    leaves = np.arange(hub + 1, hub + 1 + HUB_LEAVES)
+    edges = np.concatenate([
+        np.stack([src[keep], g0.indices[keep]], axis=1),
+        np.stack([np.full(HUB_LEAVES, hub), leaves], axis=1)])
+    return Graph.from_edges(hub + 1 + HUB_LEAVES, edges.astype(np.int64))
+
+
+def _job(g, stages=None):
+    eng = SummarizerEngine(backend="resident", T=3, seed=2, workers=4,
+                           stages=stages)
+    return eng, eng.run(g)
+
+
+@pytest.fixture(scope="module")
+def hub_job():
+    g = _hub_graph()
+    sizes: list = []
+
+    def stage_group(engine, ctx):
+        SummarizerEngine.stage_group(engine, ctx)
+        sizes.extend(len(grp) for grp in ctx.groups)
+
+    eng, summary = _job(g, {"group": stage_group})
+    return g, eng, summary, sizes
+
+
+def test_job_stats_hold_every_span_key(hub_job):
+    g, eng, summary, sizes = hub_job
+    st = eng.stats
+    for key in SPAN_STATS:
+        assert isinstance(st[key], float), key
+    for key in ("setup", "merge.host_sweep", "merge.extract", "merge.round",
+                "merge.fold", "merge.chunk.self", "merge.thunk.max",
+                "merge.thunk.cpu", "exchange.replay",
+                "exchange.bank_advance", "emit", "prune"):
+        assert st[key] > 0.0, key
+    counts = st["span_counts"]
+    assert all(isinstance(v, int) for v in counts.values())
+    assert counts["setup"] == 1
+    assert counts["merge.round"] == st["transfer"]["rounds"] > 0
+    assert counts["merge.host_sweep"] == sum(
+        s > _BATCH_MAX_GROUP for s in sizes) >= 1
+    assert counts["merge.thunk"] == (counts["merge.host_sweep"]
+                                     + counts["merge.chunk"])
+    assert counts["merge.extract"] == counts["merge.chunk"]
+    assert summary.validate_lossless(g)
+
+
+def test_job_merge_parts_add_up_to_thunk_time(hub_job):
+    _, eng, _, _ = hub_job
+    st = eng.stats
+    parts = (st["merge.host_sweep"] + st["merge.extract"] + st["merge.round"]
+             + st["merge.fold"] + st["merge.chunk.self"])
+    assert parts == pytest.approx(st["merge.thunk"], rel=0.02)
+    assert st["merge.thunk.max"] <= st["merge.thunk"]
+    assert st["merge.thunk.max"] <= st["merge_round"]
+
+
+def test_stage_spans_sum_to_stage_seconds():
+    from repro.core.spans import GLOBAL
+
+    g = GG.caveman(12, 6, 0.05, seed=5)
+    snap = GLOBAL.snapshot()
+    eng, _ = _job(g)
+    d = GLOBAL.delta_since(snap)
+    assert sum(d[f"stage.{name}"]["wall"] for name in STAGE_ORDER) \
+        == pytest.approx(sum(eng.stats[name] for name in STAGE_ORDER),
+                         rel=1e-12)
+    for name in STAGE_ORDER:
+        assert d[f"stage.{name}"]["count"] == eng.T
+    assert d["emit"]["wall"] == eng.stats["emit"]
+    assert d["prune"]["wall"] == eng.stats["prune"]
+
+
+def _host_span_names(logdir) -> set:
+    """Names of the ``slugger.`` annotations on the host planes of the
+    newest profiler trace under ``logdir``."""
+    from jax.profiler import ProfileData
+
+    path = max(Path(logdir).rglob("*.xplane.pb"),
+               key=lambda p: p.stat().st_mtime)
+    pd = ProfileData.from_file(str(path))
+    return {ev.name for plane in pd.planes if plane.name.startswith("/host:")
+            for ln in plane.lines for ev in ln.events
+            if ev.name.startswith("slugger.")}
+
+
+def test_summary_identical_under_profiler_and_spans_in_trace(hub_job,
+                                                             tmp_path):
+    import jax
+
+    g, _, plain, _ = hub_job
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        _, traced = _job(g)
+    finally:
+        jax.profiler.stop_trace()
+    assert np.array_equal(np.asarray(traced.parent), np.asarray(plain.parent))
+    assert np.array_equal(np.asarray(traced.edges), np.asarray(plain.edges))
+    names = _host_span_names(tmp_path)
+    assert "slugger.stage.merge_round" in names
+    assert {"slugger.setup", "slugger.merge.thunk", "slugger.merge.chunk",
+            "slugger.merge.round", "slugger.merge.host_sweep"} <= names
