@@ -63,6 +63,7 @@ SPAN_STATS = {
     "exchange.replay": ("exchange.replay", "wall"),
     "exchange.bank_advance": ("exchange.bank_advance", "wall"),
     "merge.host_sweep": ("merge.host_sweep", "wall"),
+    "merge.device_sweep": ("merge.device_sweep", "wall"),
     "merge.extract": ("merge.extract", "wall"),
     "merge.round": ("merge.round", "wall"),
     "merge.fold": ("merge.fold", "wall"),
@@ -431,10 +432,11 @@ class SummarizerEngine:
             spans_prev = SPANS.snapshot()
             log.info(
                 "iter %3d: θ=%.3f groups=%d merges=%d roots=%d parts=%d "
-                "host_sweeps=%d chunks=%d rounds=%d",
+                "host_sweeps=%d device_sweeps=%d chunks=%d rounds=%d",
                 t, theta, len(ctx.groups), ctx.merges, state.alive.size,
                 self.partitions,
                 it_spans.get("merge.host_sweep", {}).get("count", 0),
+                it_spans.get("merge.device_sweep", {}).get("count", 0),
                 it_spans.get("merge.chunk", {}).get("count", 0),
                 it_transfer["rounds"])
         self.stats["transfer"] = TRANSFER.delta_since(transfer0)

@@ -1021,7 +1021,10 @@ def build_merge_work(
     shape-only shells — identical chunking and member layout, but the big
     CNT/bits/colsize tensors never materialize on host because the
     resident factory extracts them on device from the adjacency bank.
-    Oversized groups keep their host `GroupWorkspace` sweep either way.
+    On that path an oversized group is a one-group shell too, and its
+    thunk sweeps the group's queue on device
+    (`ResidentBitmapArena.queue_sweep`); elsewhere it keeps its host
+    `GroupWorkspace` sweep.
     """
     groups = [np.asarray(g, dtype=np.int64) for g in groups]
     group_seeds = np.asarray(group_seeds, dtype=np.uint64)
@@ -1070,14 +1073,44 @@ def build_merge_work(
                                 height_bound=height_bound)
         return run
 
+    # on the bank path an oversized group is a one-group shell: its thunk
+    # extracts the group from the bank (``merge.extract``) and sweeps its
+    # queue on device in one program (``merge.device_sweep``), which returns
+    # the same ordered merges `_sweep_sequential` would record
+    def _device_thunk(ws, rng):
+        def run():
+            from repro import faults
+
+            with span("merge.thunk"):
+                with span("merge.extract"):
+                    arena = resident_factory(ws)
+                with span("merge.device_sweep"):
+                    perm = rng.permutation(int(ws.alive.sum()))
+                    try:
+                        pairs = arena.queue_sweep(perm, theta_to_p(theta),
+                                                  height_bound)
+                    except Exception as e:
+                        raise faults.BankFault(
+                            f"device sweep failed: {e!r}") from e
+                    for j in range(len(pairs)):  # one merge per round
+                        ws.plans[0].record(pairs[j, :1], pairs[j, 1:])
+            return len(pairs)
+        return run
+
     buckets: dict = {}
     for i, grp in enumerate(groups):
+        G = 1 << max(3, int(grp.size - 1).bit_length())
+        if grp.size > _BATCH_MAX_GROUP and shell_workspaces:
+            ws, = BatchedGroupWorkspace.build_bucket(
+                state, [grp], G, plans=[plans[i]],
+                group_seeds=group_seeds[[i]], shell=True)
+            thunks.append(_device_thunk(ws, rng_of(i)))
+            continue
         if backend == "loop" or grp.size > _BATCH_MAX_GROUP:
             ws = GroupWorkspace(state, grp, plan=plans[i])
             thunks.append(_seq_thunk(ws, rng_of(i)))
             continue
-        buckets.setdefault(1 << max(3, int(grp.size - 1).bit_length()),
-                           []).append(i)
+        buckets.setdefault(G, []).append(i)
     for G in sorted(buckets):
         idxs = buckets[G]
         for ws in BatchedGroupWorkspace.build_bucket(

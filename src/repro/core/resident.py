@@ -33,6 +33,11 @@ count tensors on device — the host workspaces become shape-only shells and
 the device bank is authoritative within a run. The host materializes bank
 rows only for verification (`host_rows`, the `sync_rows`-style contract).
 
+A one-group arena extracted from the bank can instead sweep its whole
+queue in one device program (`queue_sweep`): that is how a candidate
+group over 128 members runs on the bank path, with the host sequential
+sweep's exact decisions.
+
 `sync_rows` keeps the verification contract: tests pull selected rows back
 and assert the device fold is bit-identical to the host fold.
 
@@ -417,6 +422,36 @@ class ResidentBitmapArena:
          self._cost) = _run_round_op(
             self, "kernel.bitset_fold.fold_counts", build,
             self._state() + (self._put(instr),))
+
+    def queue_sweep(self, perm: np.ndarray, theta_p: int,
+                    height_bound) -> np.ndarray:
+        """Sweep this ONE-group arena's queue to the end on device
+        (`kernels/bitset_fold.sweep_fn`) and return its merges as (m, 2)
+        int64 local ``[a, z]`` rows in decision order.
+
+        ``perm`` is the host-drawn queue permutation of the group's rows —
+        the draw `merging._sweep_sequential` makes — and the only upload
+        besides θ̂; the merge list is the only download. One round trip.
+        """
+        import jax
+        import jax.numpy as jnp
+        from repro.kernels.bitset_fold.ops import sweep_fn
+
+        if self.Bp != 1 or self._counts is None:
+            raise RuntimeError("queue_sweep needs a one-group count arena")
+        qpos = np.zeros(self.G, dtype=np.int32)
+        qpos[np.asarray(perm)] = np.arange(len(perm), dtype=np.int32)
+        fn = sweep_fn(self.G, self.Rp, self.Wp, self.J,
+                      height_bound=height_bound)
+        args = (self._bits, self._alive, self._CNT, self._colsize,
+                self._memcol, self._s, self._selfc, self._nd, self._hgt,
+                self._cost, jnp.asarray(qpos), jnp.uint32(theta_p))
+        self.counter.add_h2d(qpos.nbytes + 4, phase="rank")
+        m, pairs = jax.device_get(fn(*args))
+        self.counter.add_d2h(pairs.nbytes + 4, phase="rank")
+        self.counter.tick_round()
+        self.rounds += 1
+        return np.asarray(pairs[: int(m)], dtype=np.int64)
 
     # --------------------------------------------------- sync-back contract
     def sync_rows(self, b: np.ndarray, g: np.ndarray) -> np.ndarray:

@@ -10,6 +10,9 @@
   resident bitmaps. Both positional buffers are donated, so the update is
   in place (the Pallas kernel additionally aliases input→output).
 
+`sweep_fn` compiles the one-group queue sweep (`ref.queue_sweep`) that
+the bank path runs for a candidate group over 128 members.
+
 Dispatch picks the Pallas kernels on TPU and their integer-exact jnp twins
 (`ref.py`) elsewhere (`kernels/common.default_use_kernel`); either path is
 bit-identical (test-enforced). With a mesh, the batch axis is shard_map'd
@@ -38,6 +41,7 @@ _FOLD_CACHE = LruCache(16)
 _ROUND_CACHE = LruCache(128)
 _FOLDC_CACHE = LruCache(128)
 _EXTRACT_CACHE = LruCache(128)
+_SWEEP_CACHE = LruCache(32)
 
 
 class _Dispatch:
@@ -256,6 +260,34 @@ def extract_fn(Bp: int, G: int, Rp: int, Wp: int, Lp: int, cap: int,
 
     fn = _Dispatch("kernel.bitset_fold.extract", bank_extract)
     _EXTRACT_CACHE[key] = fn
+    return fn
+
+
+def sweep_fn(G: int, Rp: int, Wp: int, top_j: int, *, height_bound):
+    """Compiled queue sweep of ONE group (`ref.queue_sweep`).
+
+    ``(bits (1,G,Wp) u32, alive (1,G) i8, CNT (1,G,Rp) i32, colsize
+    (1,Rp) i32, memcol/s/selfc/nd/hgt/cost (1,G) i32, qpos (G,) i32,
+    theta_p u32) -> (merges i32, pairs (G,2) i32)`` — the one-group arena
+    `ResidentBitmapArena.from_bank` extracts, swept to the end in one
+    dispatch. Nothing is donated: the loop updates its own copy in place.
+    Group sizes bucket to powers of two and column widths to pow2, so a
+    job compiles a handful of these.
+    """
+    key = ("sweep", G, Rp, Wp, top_j, height_bound)
+    fn = _SWEEP_CACHE.get(key)
+    if fn is not None:
+        return fn
+
+    @jax.jit
+    def queue_sweep(bits, alive, CNT, colsize, memcol, s, selfc, nd, hgt,
+                    cost, qpos, theta_p):
+        return ref.queue_sweep(bits[0], alive[0], CNT[0], colsize[0],
+                               memcol[0], s[0], selfc[0], nd[0], hgt[0],
+                               cost[0], qpos, theta_p, top_j, height_bound)
+
+    fn = _Dispatch("kernel.bitset_fold.sweep", queue_sweep)
+    _SWEEP_CACHE[key] = fn
     return fn
 
 

@@ -26,6 +26,9 @@ so these functions avoid floating point entirely:
   `poss_self_c` / `pair_cost_c`, mirrored by `core/merging.py` in int64),
   and the fused per-round proposal evaluation (`round_all` / `round_rows`)
   plus the count-carrying fold (`fold_pairs_counts`).
+* ``queue_sweep`` — the sequential queue sweep of one group over 128
+  members (`core/merging._sweep_sequential`) as one device loop, built from
+  the pieces above.
 """
 from __future__ import annotations
 
@@ -666,3 +669,99 @@ def fold_pairs_counts(bits, alive, dirty, CNT, colsize, memcol, s, selfc,
     cost = cost.at[a].set(crow, mode="drop")
     cost = cost.at[z].set(0, mode="drop")
     return bits, alive, dirty, CNT, colsize, s, selfc, nd, hgt, cost
+
+
+# ---------------------------------------------------------------------------
+# Queue sweep of one oversized group (Algorithm 2, one pop per iteration)
+# ---------------------------------------------------------------------------
+def queue_sweep(bits, alive, CNT, colsize, memcol, s, selfc, nd, hgt, cost,
+                qpos, theta_p, top_j: int, height_bound):
+    """The sequential queue sweep of ONE group as a single device loop —
+    the twin of `core/merging._sweep_sequential` over a dense group view.
+
+    ``bits`` (G, W) u32, ``CNT`` (G, R) i32 and the per-row/per-column
+    stats are one group's resident state (as `bank_extract_group` builds
+    it); ``qpos`` (G,) i32 is each alive row's position in the host-drawn
+    queue permutation. One iteration is one pop: the in-queue row with the
+    largest position is ``a``; the in-queue rows are its candidates, ranked
+    by (rank key desc, queue position asc) when more than ``top_j`` remain
+    and taken in queue order otherwise; the best is the first candidate of
+    the exact-rational Saving maximum; on θ̂-acceptance the pair folds
+    (`fold_pairs_counts` with one pair), ``z`` leaves the queue and ``a``
+    rejoins at its front. Every pop shrinks the queue by one, so the loop
+    runs k − 1 times. Returns ``(merges, pairs)``: the count and the
+    (G, 2) i32 ``[a, z]`` rows in merge order (rows past the count are
+    scratch).
+    """
+    i32 = jnp.int32
+    G = CNT.shape[0]
+    if G > (1 << 14):
+        raise ValueError("queue_sweep packs key·2G + position into int32; "
+                         f"G={G} is too large")
+    J = min(int(top_j), G - 1)
+    inq = alive > 0
+    nq = inq.sum(dtype=i32)
+    pairs = jnp.zeros((G, 2), i32)
+    dirty = jnp.zeros((G,), jnp.int8)   # the fold's queue mirror, unused here
+
+    def pop(carry):
+        (bits, alive, CNT, colsize, s, selfc, nd, hgt, cost, inq, qpos,
+         nq, front, m, pairs) = carry
+        a = jnp.argmax(jnp.where(inq, qpos, jnp.iinfo(i32).min)).astype(i32)
+        inq = inq.at[a].set(False)
+        # candidates: key desc then queue position asc (the host's stable
+        # argsort over queue order), or queue order alone for ≤ top_j
+        inter = popcount_u32(bits[a][None, :] & bits).sum(axis=-1, dtype=i32)
+        deg = popcount_u32(bits).sum(axis=-1, dtype=i32)
+        keys = rank_keys(inter, deg[a], deg)
+        prio = (G - 1) - qpos                    # front of the queue first
+        ranked = nq - 1 > top_j
+        ckey = jnp.where(inq, jnp.where(ranked, keys * (2 * G) + prio, prio),
+                         -1)
+        kv, idx = jax.lax.top_k(ckey, J)
+        idx = idx.astype(i32)
+        numer, denom = _row_saving_terms(
+            jnp.broadcast_to(CNT[a], (J,) + CNT.shape[1:]), CNT[idx],
+            jnp.broadcast_to(colsize, (J,) + colsize.shape),
+            jnp.broadcast_to(memcol[a], (J,)), memcol[idx],
+            jnp.broadcast_to(s[a], (J,)), s[idx],
+            jnp.broadcast_to(selfc[a], (J,)), selfc[idx],
+            jnp.broadcast_to(nd[a], (J,)), nd[idx],
+            jnp.broadcast_to(cost[a], (J,)), cost[idx])
+        valid = (kv >= 0) & (denom > 0)
+        if height_bound is not None:
+            new_h = jnp.maximum(hgt[a], hgt[idx]) + 1
+            valid = valid & (new_h <= i32(height_bound))
+        # first candidate of the maximum Saving: j wins unless a valid i has
+        # numer_i·denom_j < numer_j·denom_i — the host's strict first-wins
+        # scan, as one (J, J) exact compare
+        n_v = jnp.where(valid, numer, 1)
+        d_v = jnp.where(valid, denom, 1)
+        beats = prod_lt(n_v[:, None], d_v[None, :], n_v[None, :],
+                        d_v[:, None]) & valid[:, None]
+        top = valid & ~beats.any(axis=0)
+        best = jnp.argmax(top)
+        acc = top.any() & theta_accept(numer[best], denom[best], theta_p)
+        z = idx[best]
+
+        def fold(st):
+            instr = jnp.stack([a, z, i32(1)])[None, :]
+            (bits, alive, _, CNT, colsize, s, selfc, nd, hgt,
+             cost) = fold_pairs_counts(st[0], st[1], dirty, st[2], st[3],
+                                       memcol, st[4], st[5], st[6], st[7],
+                                       st[8], instr)
+            return bits, alive, CNT, colsize, s, selfc, nd, hgt, cost
+
+        state = jax.lax.cond(acc, fold, lambda st: st,
+                             (bits, alive, CNT, colsize, s, selfc, nd, hgt,
+                              cost))
+        inq = jnp.where(acc, inq.at[z].set(False).at[a].set(True), inq)
+        qpos = jnp.where(acc, qpos.at[a].set(front), qpos)
+        pairs = pairs.at[m].set(jnp.stack([a, z]))
+        acc32 = acc.astype(i32)
+        return state + (inq, qpos, nq - 1, front - acc32, m + acc32, pairs)
+
+    carry = (bits, alive, CNT, colsize, s, selfc, nd, hgt, cost, inq,
+             qpos.astype(i32), nq, i32(-1), i32(0), pairs)
+    out = jax.lax.while_loop(lambda c: c[11] > 1, pop, carry)
+    return out[13], out[14]

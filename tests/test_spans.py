@@ -102,7 +102,7 @@ def test_snapshot_and_delta_since():
 def _hub_graph() -> Graph:
     """Caveman cliques plus one hub with degree-one leaves: the leaves
     share a shingle, so one candidate group is over 128 members and is
-    swept on the host; the cliques fill batched chunks."""
+    swept on the device from the bank; the cliques fill batched chunks."""
     g0 = GG.caveman(30, 6, 0.05, seed=3)
     src = np.repeat(np.arange(g0.n), np.diff(g0.indptr))
     keep = src < g0.indices
@@ -138,7 +138,7 @@ def test_job_stats_hold_every_span_key(hub_job):
     st = eng.stats
     for key in SPAN_STATS:
         assert isinstance(st[key], float), key
-    for key in ("setup", "merge.host_sweep", "merge.extract", "merge.round",
+    for key in ("setup", "merge.device_sweep", "merge.extract", "merge.round",
                 "merge.fold", "merge.chunk.self", "merge.thunk.max",
                 "merge.thunk.cpu", "exchange.replay",
                 "exchange.bank_advance", "emit", "prune"):
@@ -146,20 +146,25 @@ def test_job_stats_hold_every_span_key(hub_job):
     counts = st["span_counts"]
     assert all(isinstance(v, int) for v in counts.values())
     assert counts["setup"] == 1
-    assert counts["merge.round"] == st["transfer"]["rounds"] > 0
-    assert counts["merge.host_sweep"] == sum(
+    # a device sweep is one round trip of its own
+    assert (counts["merge.round"] + counts["merge.device_sweep"]
+            == st["transfer"]["rounds"])
+    assert counts["merge.round"] > 0
+    assert counts["merge.device_sweep"] == sum(
         s > _BATCH_MAX_GROUP for s in sizes) >= 1
-    assert counts["merge.thunk"] == (counts["merge.host_sweep"]
+    assert "merge.host_sweep" not in counts
+    assert st["merge.host_sweep"] == 0.0
+    assert counts["merge.thunk"] == (counts["merge.device_sweep"]
                                      + counts["merge.chunk"])
-    assert counts["merge.extract"] == counts["merge.chunk"]
+    assert counts["merge.extract"] == counts["merge.thunk"]
     assert summary.validate_lossless(g)
 
 
 def test_job_merge_parts_add_up_to_thunk_time(hub_job):
     _, eng, _, _ = hub_job
     st = eng.stats
-    parts = (st["merge.host_sweep"] + st["merge.extract"] + st["merge.round"]
-             + st["merge.fold"] + st["merge.chunk.self"])
+    parts = (st["merge.device_sweep"] + st["merge.extract"]
+             + st["merge.round"] + st["merge.fold"] + st["merge.chunk.self"])
     assert parts == pytest.approx(st["merge.thunk"], rel=0.02)
     assert st["merge.thunk.max"] <= st["merge.thunk"]
     assert st["merge.thunk.max"] <= st["merge_round"]
@@ -209,4 +214,4 @@ def test_summary_identical_under_profiler_and_spans_in_trace(hub_job,
     names = _host_span_names(tmp_path)
     assert "slugger.stage.merge_round" in names
     assert {"slugger.setup", "slugger.merge.thunk", "slugger.merge.chunk",
-            "slugger.merge.round", "slugger.merge.host_sweep"} <= names
+            "slugger.merge.round", "slugger.merge.device_sweep"} <= names

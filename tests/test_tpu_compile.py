@@ -7,7 +7,9 @@ The arenas pass the group size G through unpadded — the engine's size
 buckets give 8…128, and the kernels accept any G up to 128, so odd and tiny
 G are compiled too — and W, the packed-bitmap word count, is a power of
 two. The serving kernel sees the fixed query-slot batch (or a short one)
-and power-of-two interval/probe widths.
+and power-of-two interval/probe widths. The queue sweep of a group over
+128 members is a jitted loop with no Pallas kernel: it is compiled at the
+two oversized group buckets and at a narrow and a hub-wide column width.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU compiler's library, so a call at collection time
@@ -23,7 +25,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.bitset_fold.kernel import jaccard_topj_kernel
-from repro.kernels.bitset_fold.ops import fold_counts_fn
+from repro.kernels.bitset_fold.ops import fold_counts_fn, sweep_fn
 from repro.kernels.interval_expand.kernel import interval_count_kernel
 
 GROUPS = (2, 37, 128)
@@ -95,6 +97,23 @@ def test_bitset_fold_compiles(one_chip, G, W):
         _spec(one_chip, (B, G, R), i32), _spec(one_chip, (B, R), i32),
         *per_g, _spec(one_chip, (B, P, 3), i32))
     _assert_kernel(compiled)
+
+
+@pytest.mark.parametrize("Rp", (1024, 16384))
+@pytest.mark.parametrize("G", (256, 512))
+def test_queue_sweep_compiles(one_chip, G, Rp):
+    """The one-group sweep program at the arena's extracted shapes:
+    ``Wp`` u32 words of 32 columns each, the bank path's pow2 widths."""
+    Wp = Rp // 32
+    fn = sweep_fn(G, Rp, Wp, 16, height_bound=None)
+    i32 = jnp.int32
+    compiled = fn.jitted.lower(
+        _spec(one_chip, (1, G, Wp), jnp.uint32),
+        _spec(one_chip, (1, G), jnp.int8),
+        _spec(one_chip, (1, G, Rp), i32), _spec(one_chip, (1, Rp), i32),
+        *[_spec(one_chip, (1, G), i32) for _ in range(6)],
+        _spec(one_chip, (G,), i32), _spec(one_chip, (), jnp.uint32)).compile()
+    assert " while(" in compiled.as_text()
 
 
 @pytest.mark.parametrize("B,E,P", [
