@@ -32,12 +32,14 @@ import functools
 import numpy as np
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.slugger import SluggerState, _emit_encoding
 from repro.core.minhash import rootwise_min
 from repro.core.pruning import prune
+from repro.core.spans import span
 from repro.graphs.csr import Graph
+from repro.kernels.common import LruCache, mesh_content_key, shard_map_no_check
 
 MAXU = jnp.uint32(0xFFFFFFFF)
 
@@ -63,8 +65,11 @@ def shingles_sharded(mesh, data_axes=("data",)):
 
     Returns a function (src, dst, n_static, a, b) -> (n,) uint32, where the
     edge arrays are sharded along ``data_axes`` and padded with src == n
-    (padding rows fold into a dummy segment).
+    (padding rows fold into a dummy segment). One program per edge count
+    and ``n``: the hash constants ``a``/``b`` are traced uint32 scalars, so
+    every rehash seed reuses it.
     """
+    edge_spec = P(data_axes if len(data_axes) > 1 else data_axes[0])
 
     def _local(src, dst, h_self, a, b):
         n = h_self.shape[0]
@@ -75,21 +80,26 @@ def shingles_sharded(mesh, data_axes=("data",)):
             local = jax.lax.pmin(local, ax)
         return local
 
-    def fn(src, dst, n, a, b):
+    @functools.partial(jax.jit, static_argnums=(2,))
+    def shingles(src, dst, n, a, b):
         h_self = _hash_u32(jnp.arange(n, dtype=jnp.uint32), a, b)
-        edge_spec = P(data_axes if len(data_axes) > 1 else data_axes[0])
         return jax.shard_map(
-            functools.partial(_local, a=a, b=b),
-            mesh=mesh,
-            in_specs=(edge_spec, edge_spec, P(None)),
+            _local, mesh=mesh,
+            in_specs=(edge_spec, edge_spec, P(None), P(), P()),
             out_specs=P(None),
-        )(src, dst, h_self)
+        )(src, dst, h_self, a, b)
+
+    def fn(src, dst, n, a, b):
+        return shingles(src, dst, int(n), np.uint32(a), np.uint32(b))
 
     return fn
 
 
 def root_shingles_jax(node_sh, root_of, n_ids):
     return jax.ops.segment_min(node_sh, root_of, num_segments=n_ids)
+
+
+_MESH_SHINGLE_CACHE = LruCache(8)  # jitted sharded shingles, by mesh
 
 
 def _data_axes_of(mesh, data_axes):
@@ -115,26 +125,37 @@ def shingle_provider(g: Graph, mesh, data_axes=None):
     src = np.repeat(np.arange(g.n), np.diff(g.indptr)).astype(np.int32)
     dst = np.asarray(g.indices, dtype=np.int32)
     pad = (-src.size) % max(n_shards, 1)
-    src_p = jnp.asarray(np.concatenate([src, np.full(pad, g.n, np.int32)]))
-    dst_p = jnp.asarray(np.concatenate([dst, np.zeros(pad, np.int32)]))
-    sharded = shingles_sharded(mesh, data_axes)
+    edges = NamedSharding(mesh, P(data_axes if len(data_axes) > 1
+                                  else data_axes[0]))
+    src_p = jax.device_put(
+        np.concatenate([src, np.full(pad, g.n, np.int32)]), edges)
+    dst_p = jax.device_put(
+        np.concatenate([dst, np.zeros(pad, np.int32)]), edges)
+    # one compiled program for every job on an equivalent mesh
+    key = (mesh_content_key(mesh), data_axes)
+    sharded = _MESH_SHINGLE_CACHE.get(key)
+    if sharded is None:
+        sharded = _MESH_SHINGLE_CACHE[key] = shingles_sharded(mesh,
+                                                              data_axes)
 
     def for_roots(root_of: np.ndarray):
         root_of = np.asarray(root_of, dtype=np.int64)
 
         def shingle_fn(sub_seed: int, n_ids: int) -> np.ndarray:
-            a = np.uint32((2654435761 * (int(sub_seed) | 1)) & 0xFFFFFFFF)
-            b = np.uint32((int(sub_seed) * 0x9E3779B9) & 0xFFFFFFFF)
-            node_sh = np.asarray(sharded(src_p, dst_p, g.n, a, b))
-            return rootwise_min(node_sh.astype(np.int64), root_of, n_ids,
-                                1 << 32)
+            # one rehash: the sharded dispatch, its download and the host
+            # root-level segment-min
+            with span("mesh.shingle"):
+                a = np.uint32((2654435761 * (int(sub_seed) | 1))
+                              & 0xFFFFFFFF)
+                b = np.uint32((int(sub_seed) * 0x9E3779B9) & 0xFFFFFFFF)
+                node_sh = np.asarray(sharded(src_p, dst_p, g.n, a, b))
+                return rootwise_min(node_sh.astype(np.int64), root_of,
+                                    n_ids, 1 << 32)
 
         return shingle_fn
 
     return for_roots
 
-
-from repro.kernels.common import LruCache, mesh_content_key, shard_map_no_check
 
 _MESH_JACCARD_CACHE = LruCache(8)  # compiled shard_map executables, by shape
 

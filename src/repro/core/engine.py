@@ -47,7 +47,7 @@ from repro.core.merging import apply_plans, build_merge_work
 from repro.core.minhash import candidate_groups
 from repro.core.pruning import prune
 from repro.core.slugger import SluggerState, _emit_encoding
-from repro.core.spans import GLOBAL as SPANS, span
+from repro.core.spans import COUNTS, GLOBAL as SPANS, span
 from repro.graphs.partitioned import PartitionedGraph, as_partitioned
 
 log = logging.getLogger("repro.engine")
@@ -71,7 +71,21 @@ SPAN_STATS = {
     "merge.thunk": ("merge.thunk", "wall"),
     "merge.thunk.max": ("merge.thunk", "max"),
     "merge.thunk.cpu": ("merge.thunk", "cpu"),
+    # what a mesh run adds (0.0 on one device): each rehash's sharded
+    # shingles, the arenas' sharded uploads, and the host fill of a dense
+    # workspace chunk in pack (a shell chunk of the bank path has none)
+    "mesh.shingle": ("mesh.shingle", "wall"),
+    "mesh.upload": ("mesh.upload", "wall"),
+    "pack.fill": ("pack.fill", "wall"),
 }
+
+# flat entries of ``SummarizerEngine.stats`` read from one job's tallies
+# (`spans.COUNTS`), as floats like the spans' seconds: the mesh-sharded
+# arenas, their rows real and padded to a multiple of the shard count, and
+# the devices holding a shard of each, summed over arenas (0.0 on one
+# device)
+COUNT_STATS = ("mesh.arenas", "mesh.rows", "mesh.rows_padded",
+               "mesh.shard_devices")
 
 
 class IterationContext:
@@ -147,6 +161,7 @@ class SummarizerEngine:
         self._rank_dispatch = None
         self._resident_factory = None
         self._run_ctx = None
+        self._devices = 1
 
     # ------------------------------------------------------------- plumbing
     def _mesh_active(self):
@@ -173,6 +188,7 @@ class SummarizerEngine:
         self._resident_factory = None
         self._run_ctx = None
         mesh = self._mesh_active()
+        self._devices = 1 if mesh is None else int(mesh.size)
         if self.backend == "resident":
             from repro.core.resident import ResidentBitmapArena
 
@@ -366,6 +382,7 @@ class SummarizerEngine:
         from repro.core.transfer import GLOBAL as TRANSFER
 
         spans0 = SPANS.snapshot()
+        counts0 = COUNTS.snapshot()
         with span("setup"):
             pg = as_partitioned(g, self.partitions)
             state = SluggerState(pg.to_graph())
@@ -432,9 +449,10 @@ class SummarizerEngine:
             spans_prev = SPANS.snapshot()
             log.info(
                 "iter %3d: θ=%.3f groups=%d merges=%d roots=%d parts=%d "
-                "host_sweeps=%d device_sweeps=%d chunks=%d rounds=%d",
+                "devices=%d host_sweeps=%d device_sweeps=%d chunks=%d "
+                "rounds=%d",
                 t, theta, len(ctx.groups), ctx.merges, state.alive.size,
-                self.partitions,
+                self.partitions, self._devices,
                 it_spans.get("merge.host_sweep", {}).get("count", 0),
                 it_spans.get("merge.device_sweep", {}).get("count", 0),
                 it_spans.get("merge.chunk", {}).get("count", 0),
@@ -446,6 +464,9 @@ class SummarizerEngine:
             self.stats[key] = float(spans.get(name, {}).get(field, 0.0))
         self.stats["span_counts"] = {name: d["count"]
                                      for name, d in spans.items()}
+        counts = COUNTS.delta_since(counts0)
+        for key in COUNT_STATS:
+            self.stats[key] = float(counts.get(key, 0))
         return state, pg
 
     def run(self, g, checkpoint_dir=None, resume: bool = False,

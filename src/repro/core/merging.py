@@ -25,6 +25,7 @@ makes every engine lossless by construction regardless of merge order.
 """
 from __future__ import annotations
 
+import contextlib
 import logging
 
 import numpy as np
@@ -701,18 +702,23 @@ class BatchedGroupWorkspace:
         col_pos = np.arange(uniq.size) - col_bounds[col_grp]
         out: list = []
         for ci, (bc, rc) in enumerate(chunks):
-            ws = BatchedGroupWorkspace(state, bc, G, max(int(rc), 1),
-                                       shell=shell)
             msel = mem_chunk == ci
             esel = ent_chunk == ci
             csel = col_chunk == ci
-            ws._fill(
-                newb_of_group[grp_of_member[msel]], row_in_group[msel],
-                colidx[:nm][msel], members_flat[msel],
-                newb_of_group[ent_grp[esel]], row_in_group[seg[esel]],
-                colidx[nm:][esel], cnt[esel],
-                newb_of_group[col_grp[csel]], col_pos[csel], (uniq % big)[csel],
-            )
+            # a dense chunk's tensors are built on the host here; a shell's
+            # come from the device bank
+            with (contextlib.nullcontext() if shell
+                  else span("pack.fill")):
+                ws = BatchedGroupWorkspace(state, bc, G, max(int(rc), 1),
+                                           shell=shell)
+                ws._fill(
+                    newb_of_group[grp_of_member[msel]], row_in_group[msel],
+                    colidx[:nm][msel], members_flat[msel],
+                    newb_of_group[ent_grp[esel]], row_in_group[seg[esel]],
+                    colidx[nm:][esel], cnt[esel],
+                    newb_of_group[col_grp[csel]], col_pos[csel],
+                    (uniq % big)[csel],
+                )
             gsel = np.flatnonzero(chunk_of_group == ci)
             if group_seeds is not None:
                 ws.gseed[newb_of_group[gsel]] = np.asarray(

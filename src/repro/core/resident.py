@@ -49,12 +49,13 @@ bytes-per-iteration reduction on these numbers.
 """
 from __future__ import annotations
 
+import contextlib
 import logging
 
 import numpy as np
 
 from repro import faults
-from repro.core.spans import span
+from repro.core.spans import COUNTS, span
 from repro.core.transfer import GLOBAL as TRANSFER
 
 log = logging.getLogger("repro.engine")
@@ -134,9 +135,17 @@ class ResidentBitmapArena:
         alive_p = np.zeros((self.Bp, G), dtype=np.int8)  # 1 byte/row on the wire
         alive_p[:B] = np.asarray(alive, dtype=bool)
         self._put = self._sharder(jax)
-        self._bits = self._put(bits_p)
-        self._alive = self._put(alive_p)
+        with self._upload_span():
+            self._bits = self._put(bits_p)
+            self._alive = self._put(alive_p)
         counter.add_h2d(bits_p.nbytes + alive_p.nbytes, phase="upload")
+        if mesh is not None:
+            # rows real and padded, and the devices holding a shard
+            COUNTS.add("mesh.arenas", 1)
+            COUNTS.add("mesh.rows", self.B)
+            COUNTS.add("mesh.rows_padded", self.Bp)
+            COUNTS.add("mesh.shard_devices", len(
+                {sh.device for sh in self._bits.addressable_shards}))
         self.rounds = 0
         self._K = 0            # padded dirty-row count of the round op
         self.Rp = 0            # set by attach_counts
@@ -179,11 +188,12 @@ class ResidentBitmapArena:
             arr[:B] = src
         dirty_p = np.zeros((self.Bp, G), dtype=np.int8)
         dirty_p[:B] = np.asarray(alive, dtype=bool)
-        self._CNT = self._put(cnt_p)
-        self._colsize = self._put(colsize_p)
-        (self._memcol, self._s, self._selfc, self._nd, self._hgt,
-         self._cost) = [self._put(a) for a in per_g]
-        self._dirty = self._put(dirty_p)
+        with self._upload_span():
+            self._CNT = self._put(cnt_p)
+            self._colsize = self._put(colsize_p)
+            (self._memcol, self._s, self._selfc, self._nd, self._hgt,
+             self._cost) = [self._put(a) for a in per_g]
+            self._dirty = self._put(dirty_p)
         self._counts = True
         self.counter.add_h2d(cnt_p.nbytes + colsize_p.nbytes +
                              sum(a.nbytes for a in per_g) + dirty_p.nbytes,
@@ -260,6 +270,11 @@ class ResidentBitmapArena:
         spec = P(self.axes if len(self.axes) > 1 else self.axes[0])
         sh = NamedSharding(self.mesh, spec)
         return lambda arr: jax.device_put(arr, sh)
+
+    def _upload_span(self):
+        """``mesh.upload`` around a sharded upload; nothing on one device."""
+        return (span("mesh.upload") if self.mesh is not None
+                else contextlib.nullcontext())
 
     def _replicate(self, arr):
         if self.mesh is None:

@@ -24,6 +24,11 @@ never synchronises the device; it ends where the code it wraps ends.
 totals, and ``snapshot`` / ``delta_since`` so the engine can report one
 job's spans (`SummarizerEngine.stats`). ``max`` in a delta is the longest
 single span closed inside the interval.
+
+`COUNTS.add(name, n)` adds to a plain integer tally (same lock
+discipline, same snapshot and delta), for what the program counts rather
+than times: the rows of the mesh-sharded arenas and the devices holding
+their shards.
 """
 from __future__ import annotations
 
@@ -138,3 +143,30 @@ class span:
         self.wall = wall
         self.totals.add(self.name, wall, cpu, wall - self._children)
         return False
+
+
+class Counts:
+    """Per-name integer tallies (monotonic; snapshot + delta)."""
+
+    __slots__ = ("_totals", "_lock")
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._totals = {}
+
+    def add(self, name: str, n: int):
+        with self._lock:
+            self._totals[name] = self._totals.get(name, 0) + int(n)
+
+    def snapshot(self) -> dict:
+        with self._lock:
+            return dict(self._totals)
+
+    def delta_since(self, snap: dict) -> dict:
+        """Tallies added since ``snap``, per name that grew."""
+        now = self.snapshot()
+        return {name: v - snap.get(name, 0) for name, v in now.items()
+                if v != snap.get(name, 0)}
+
+
+COUNTS = Counts()

@@ -4,7 +4,11 @@ reports in ``engine.stats``, and the spans landing in a profiler trace
 without changing the summary."""
 from __future__ import annotations
 
+import json
+import os
+import subprocess
 import sys
+import textwrap
 import threading
 import time
 from pathlib import Path
@@ -12,9 +16,10 @@ from pathlib import Path
 import numpy as np
 import pytest
 
-from repro.core.engine import SPAN_STATS, STAGE_ORDER, SummarizerEngine
+from repro.core.engine import (COUNT_STATS, SPAN_STATS, STAGE_ORDER,
+                               SummarizerEngine)
 from repro.core.merging import _BATCH_MAX_GROUP
-from repro.core.spans import SpanTotals, span
+from repro.core.spans import Counts, SpanTotals, span
 from repro.graphs import generators as GG
 from repro.graphs.csr import Graph
 
@@ -99,6 +104,30 @@ def test_snapshot_and_delta_since():
     assert totals.delta_since({})["a"]["count"] == 6
 
 
+def test_counts_snapshot_and_delta_under_threads():
+    counts = Counts()
+    counts.add("rows", 5)
+    snap = counts.snapshot()
+    assert snap == {"rows": 5}
+    assert counts.delta_since(snap) == {}
+
+    def work():
+        for _ in range(1000):
+            counts.add("rows", 2)
+            counts.add("arenas", 1)
+
+    threads = [threading.Thread(target=work) for _ in range(4)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=60)
+    assert counts.delta_since(snap) == {"rows": 8000, "arenas": 4000}
+    assert counts.delta_since({}) == {"rows": 8005, "arenas": 4000}
+
+
+MESH_SPANS = ("mesh.shingle", "mesh.upload", "pack.fill")
+
+
 def _hub_graph() -> Graph:
     """Caveman cliques plus one hub with degree-one leaves: the leaves
     share a shingle, so one candidate group is over 128 members and is
@@ -160,6 +189,14 @@ def test_job_stats_hold_every_span_key(hub_job):
     assert summary.validate_lossless(g)
 
 
+def test_one_device_bank_job_reads_no_mesh_span_or_tally(hub_job):
+    _, eng, _, _ = hub_job
+    st = eng.stats
+    for key in MESH_SPANS + COUNT_STATS:
+        assert st[key] == 0.0, key
+        assert key not in st["span_counts"], key
+
+
 def test_job_merge_parts_add_up_to_thunk_time(hub_job):
     _, eng, _, _ = hub_job
     st = eng.stats
@@ -215,3 +252,88 @@ def test_summary_identical_under_profiler_and_spans_in_trace(hub_job,
     assert "slugger.stage.merge_round" in names
     assert {"slugger.setup", "slugger.merge.thunk", "slugger.merge.chunk",
             "slugger.merge.round", "slugger.merge.device_sweep"} <= names
+
+
+MESH_JOB = textwrap.dedent("""
+    import os, sys
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    import json, tempfile
+    import numpy as np
+    import jax
+    from jax.sharding import Mesh
+    sys.path.insert(0, sys.argv[1])
+    import test_spans as T
+    from repro.core.engine import SummarizerEngine
+
+    g = T._hub_graph()
+    mesh = Mesh(np.array(jax.devices()), ("data",))
+
+    def job():
+        eng = SummarizerEngine(backend="resident", partitions=4, T=3, seed=2,
+                               workers=4, mesh=mesh)
+        s = eng.run(g)
+        return eng, s, np.asarray(s.parent), np.asarray(s.edges)
+
+    eng, summary, parent, edges = job()
+    logdir = tempfile.mkdtemp()
+    jax.profiler.start_trace(logdir)
+    try:
+        _, _, t_parent, t_edges = job()
+    finally:
+        jax.profiler.stop_trace()
+    one = SummarizerEngine(backend="resident", partitions=4, T=3, seed=2,
+                           workers=4, mesh=Mesh(np.array(jax.devices()[:1]),
+                                                ("data",))).run(g)
+    st = {k: v for k, v in eng.stats.items()
+          if isinstance(v, (int, float))}
+    print("RESULT " + json.dumps({
+        "stats": st, "span_counts": eng.stats["span_counts"],
+        "traced_same": bool(np.array_equal(parent, t_parent)
+                            and np.array_equal(edges, t_edges)),
+        "one_device_same": bool(np.array_equal(parent, one.parent)
+                                and np.array_equal(edges, one.edges)),
+        "lossless": bool(summary.validate_lossless(g)),
+        "trace_spans": sorted(T._host_span_names(logdir))}))
+""")
+
+
+@pytest.fixture(scope="module")
+def mesh_job():
+    """One resident job of the hub graph on a mesh of four virtual devices
+    (a subprocess: the devices must exist before JAX starts), its summary
+    once more under a profiler session, and the one-device summary."""
+    env = dict(os.environ)
+    env.pop("XLA_FLAGS", None)
+    env["PYTHONPATH"] = os.pathsep.join(
+        [str(Path(__file__).resolve().parents[1] / "src"),
+         env.get("PYTHONPATH", "")])
+    r = subprocess.run(
+        [sys.executable, "-c", MESH_JOB, str(Path(__file__).resolve().parent)],
+        capture_output=True, text=True, env=env, timeout=300)
+    lines = [ln for ln in r.stdout.splitlines() if ln.startswith("RESULT ")]
+    assert r.returncode == 0 and lines, r.stderr[-3000:]
+    return json.loads(lines[-1][len("RESULT "):])
+
+
+def test_mesh_job_reads_its_spans_and_tallies(mesh_job):
+    st, counts = mesh_job["stats"], mesh_job["span_counts"]
+    for key in MESH_SPANS:
+        assert st[key] > 0.0 and counts[key] > 0, key
+    arenas, rows, padded = (st["mesh.arenas"], st["mesh.rows"],
+                            st["mesh.rows_padded"])
+    assert arenas == counts["merge.chunk"] > 0
+    assert 0 < rows <= padded and padded % 4 == 0
+    # every arena's bits hold a shard on each of the four devices
+    assert st["mesh.shard_devices"] == 4 * arenas
+    # no bank on a mesh: the oversized group is swept on the host
+    assert counts["merge.host_sweep"] >= 1
+    assert "merge.device_sweep" not in counts
+    assert counts["mesh.upload"] == 2 * arenas
+
+
+def test_mesh_summary_identical_under_profiler_and_to_one_device(mesh_job):
+    assert mesh_job["traced_same"] and mesh_job["one_device_same"]
+    assert mesh_job["lossless"]
+    assert {"slugger.mesh.shingle", "slugger.mesh.upload",
+            "slugger.pack.fill"} <= set(mesh_job["trace_spans"])

@@ -11,6 +11,13 @@ and power-of-two interval/probe widths. The queue sweep of a group over
 128 members is a jitted loop with no Pallas kernel: it is compiled at the
 two oversized group buckets and at a narrow and a hub-wide column width.
 
+The mesh path shards the arenas' group axis over all four chips of the
+v5e:2x2 host: the ranking (`topj_fn`) and the count-carrying fold are
+compiled sharded with the Pallas kernel inside each shard, and the
+proposal round, whose every-row evaluation under a mesh is the jnp
+`ref.round_all`, compiles to one program per shard with no collective
+between the chips.
+
 The topology is described inside a fixture, never at import: only one
 process may load the TPU compiler's library, so a call at collection time
 would break the other test workers.
@@ -21,11 +28,14 @@ import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
 
 from repro.kernels.bitset_fold.kernel import jaccard_topj_kernel
-from repro.kernels.bitset_fold.ops import fold_counts_fn, sweep_fn
+from repro.kernels.bitset_fold.ops import (fold_counts_fn, round_fn,
+                                           sweep_fn, topj_fn)
 from repro.kernels.interval_expand.kernel import interval_count_kernel
 
 GROUPS = (2, 37, 128)
@@ -46,6 +56,14 @@ def topo():
 @pytest.fixture(scope="module")
 def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def four_chips(topo):
+    """The host's four chips as the engine's 1-D data mesh, with the
+    arenas' sharding (group axis over the chips) and a replicated one."""
+    mesh = Mesh(np.array(topo.devices[:4]), ("data",))
+    return (mesh, NamedSharding(mesh, P("data")), NamedSharding(mesh, P()))
 
 
 @pytest.fixture(scope="module", autouse=True)
@@ -97,6 +115,59 @@ def test_bitset_fold_compiles(one_chip, G, W):
         _spec(one_chip, (B, G, R), i32), _spec(one_chip, (B, R), i32),
         *per_g, _spec(one_chip, (B, P, 3), i32))
     _assert_kernel(compiled)
+
+
+MESH_SHAPES = [(8, 2), (37, 32), (128, 512)]   # (G, W)
+COLLECTIVES = ("all-gather", "all-reduce", "all-to-all",
+               "collective-permute", "reduce-scatter")
+
+
+def _arena_specs(sharded, B, G, R, W):
+    """The resident state the round and fold ops take, sharded as the
+    mesh arena's `device_put` places it."""
+    i32 = jnp.int32
+    return ([_spec(sharded, (B, G, W), jnp.uint32),
+             _spec(sharded, (B, G), jnp.int8), _spec(sharded, (B, G), jnp.int8),
+             _spec(sharded, (B, G, R), i32), _spec(sharded, (B, R), i32)]
+            + [_spec(sharded, (B, G), i32) for _ in range(6)])
+
+
+@pytest.mark.parametrize("G,W", MESH_SHAPES)
+def test_sharded_topj_compiles(four_chips, G, W):
+    mesh, sharded, replicated = four_chips
+    B, J, n_pad = 8, max(1, min(16, G - 1)), 64
+    fn = topj_fn(B, G, W, J, n_pad, use_kernel=True, interpret=False,
+                 mesh=mesh)
+    compiled = fn.compile(_spec(sharded, (B, G, W), jnp.uint32),
+                          _spec(sharded, (B, G), jnp.int8),
+                          _spec(replicated, (n_pad, 2), jnp.int32))
+    _assert_kernel(compiled)
+
+
+@pytest.mark.parametrize("G,W", MESH_SHAPES)
+def test_sharded_round_compiles_without_collectives(four_chips, G, W):
+    mesh, sharded, replicated = four_chips
+    B, R, J = 8, 32 * W, max(1, min(16, G - 1))
+    fn = round_fn(B, G, R, W, 64, J, J, height_bound=None, use_kernel=True,
+                  interpret=False, mesh=mesh)
+    compiled = fn.compile(*_arena_specs(sharded, B, G, R, W),
+                          _spec(replicated, (), jnp.uint32))
+    text = compiled.as_text()
+    assert not [c for c in COLLECTIVES if c in text]
+    # one shard of the group axis on each chip
+    assert f"u32[{B // 4},{G},{W}]" in text
+
+
+@pytest.mark.parametrize("G,W", MESH_SHAPES)
+def test_sharded_fold_counts_compiles(four_chips, G, W):
+    mesh, sharded, _ = four_chips
+    B, R, P_pairs = 8, 32 * W, max(1, G // 2)
+    fn = fold_counts_fn(B, G, R, W, P_pairs, use_kernel=True,
+                        interpret=False, mesh=mesh)
+    compiled = fn.compile(*_arena_specs(sharded, B, G, R, W),
+                          _spec(sharded, (B, P_pairs, 3), jnp.int32))
+    _assert_kernel(compiled)
+    assert not [c for c in COLLECTIVES if c in compiled.as_text()]
 
 
 @pytest.mark.parametrize("Rp", (1024, 16384))
