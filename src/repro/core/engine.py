@@ -43,7 +43,8 @@ from concurrent.futures import ThreadPoolExecutor
 import numpy as np
 
 from repro import faults
-from repro.core.merging import apply_plans, build_merge_work
+from repro.core.merging import (_BATCH_MAX_GROUP, apply_plans,
+                                build_merge_work)
 from repro.core.minhash import candidate_groups
 from repro.core.pruning import prune
 from repro.core.slugger import SluggerState, _emit_encoding
@@ -82,10 +83,11 @@ SPAN_STATS = {
 # flat entries of ``SummarizerEngine.stats`` read from one job's tallies
 # (`spans.COUNTS`), as floats like the spans' seconds: the mesh-sharded
 # arenas, their rows real and padded to a multiple of the shard count, and
-# the devices holding a shard of each, summed over arenas (0.0 on one
-# device)
+# the devices holding a shard of each, summed over arenas; and the devices
+# that swept at least one group over 128 members, summed over iterations
+# (all 0.0 on one device)
 COUNT_STATS = ("mesh.arenas", "mesh.rows", "mesh.rows_padded",
-               "mesh.shard_devices")
+               "mesh.shard_devices", "mesh.sweep_devices")
 
 
 class IterationContext:
@@ -93,7 +95,7 @@ class IterationContext:
 
     __slots__ = ("t", "theta", "state", "pg", "ss_groups", "ss_merge",
                  "shingle_fn", "groups", "group_children", "group_seeds",
-                 "plans", "thunks", "merges")
+                 "plans", "thunks", "merges", "sweep_chips")
 
     def __init__(self, t: int, theta: float, state, pg):
         self.t = t
@@ -107,6 +109,7 @@ class IterationContext:
         self.plans = []
         self.thunks = []
         self.merges = 0
+        self.sweep_chips = set()  # devices given a device sweep in pack
 
 
 class SummarizerEngine:
@@ -162,6 +165,7 @@ class SummarizerEngine:
         self._resident_factory = None
         self._run_ctx = None
         self._devices = 1
+        self._sweep_devices = None
 
     # ------------------------------------------------------------- plumbing
     def _mesh_active(self):
@@ -187,9 +191,14 @@ class SummarizerEngine:
         self._rank_dispatch = None
         self._resident_factory = None
         self._run_ctx = None
+        self._sweep_devices = None
         mesh = self._mesh_active()
         self._devices = 1 if mesh is None else int(mesh.size)
         if self.backend == "resident":
+            if mesh is not None:
+                # no bank on a mesh: a group over 128 members is swept on
+                # one of the mesh's devices from a host-filled arena
+                self._sweep_devices = list(mesh.devices.flat)
             from repro.core.resident import ResidentBitmapArena
 
             def factory(ws, _mesh=mesh, _j=self.top_j):
@@ -256,11 +265,23 @@ class SummarizerEngine:
         groups = ctx.groups
         ctx.plans = [None] * len(groups)
         ctx.thunks = []
+        ctx.sweep_chips = set()
         if not groups:
             return
         part_of_group = self._group_partitions(ctx)
         shell = (self.backend == "resident" and self._run_ctx is not None
                  and getattr(self._run_ctx, "bank", None) is not None)
+        sweep_dev = None
+        if self._sweep_devices:
+            # the groups over 128 members go round-robin, in group order,
+            # to the mesh's devices: the same job places the same sweeps
+            sweep_dev = [None] * len(groups)
+            devs = self._sweep_devices
+            over = [i for i, grp in enumerate(groups)
+                    if len(grp) > _BATCH_MAX_GROUP]
+            for n, i in enumerate(over):
+                sweep_dev[i] = devs[n % len(devs)]
+            ctx.sweep_chips = {sweep_dev[i] for i in over}
         for p in np.unique(part_of_group):
             idxs = np.flatnonzero(part_of_group == p)
             plans_p, thunks_p = build_merge_work(
@@ -271,7 +292,9 @@ class SummarizerEngine:
                 top_j=self.top_j, height_bound=self.height_bound,
                 backend=self.backend, rank_dispatch=self._rank_dispatch,
                 resident_factory=self._resident_factory,
-                shell_workspaces=shell)
+                shell_workspaces=shell,
+                sweep_devices=(None if sweep_dev is None
+                               else [sweep_dev[i] for i in idxs]))
             for li, gi in enumerate(idxs):
                 ctx.plans[int(gi)] = plans_p[li]
             ctx.thunks.extend(thunks_p)
@@ -286,6 +309,8 @@ class SummarizerEngine:
         else:
             for thunk in ctx.thunks:
                 thunk()
+        if ctx.sweep_chips:
+            COUNTS.add("mesh.sweep_devices", len(ctx.sweep_chips))
 
     def stage_exchange(self, ctx: IterationContext):
         """Replay all recorded merge rounds against the global state in
@@ -321,17 +346,21 @@ class SummarizerEngine:
             return apply_plans(state, plans)
 
     def _degrade_to_host(self, state, site: str, exc) -> None:
-        """§11 degradation policy: drop the resident run context (bank,
-        device root map, device shingles) and finish the run on the
-        host-rebuilt workspace path — bit-identical by the unified-u32
-        shingle/ranking contract, just slower. Counted in
-        ``stats["degradations"]`` via the global ledger."""
+        """§11 degradation policy: drop the device state that failed and
+        finish the run on host workspaces and host sweeps — bit-identical
+        by the unified-u32 shingle/ranking contract, just slower. On one
+        device that is the resident run context (bank, device root map,
+        device shingles); on a mesh, the device sweeps of the groups over
+        128 members. Counted in ``stats["degradations"]`` via the global
+        ledger."""
         faults.DEGRADATIONS.record(site, exc)
         log.warning("degrading to host workspace path after %s fault: %r",
                     site, exc)
-        self._run_ctx = None
-        from repro.core.minhash import host_shingle_provider
-        self._shingle_provider = host_shingle_provider(state.g)
+        self._sweep_devices = None
+        if self._run_ctx is not None:
+            self._run_ctx = None
+            from repro.core.minhash import host_shingle_provider
+            self._shingle_provider = host_shingle_provider(state.g)
 
     def _group_partitions(self, ctx: IterationContext) -> np.ndarray:
         """Partition of each group = owner of its smallest member root's
@@ -392,6 +421,7 @@ class SummarizerEngine:
         self.stats = {"merges": 0, "transfer_iters": []}
         transfer_prev = transfer0
         spans_prev = SPANS.snapshot()
+        counts_prev = COUNTS.snapshot()
         ckpt = None
         fingerprint = None
         plan_log: list = []
@@ -424,13 +454,15 @@ class SummarizerEngine:
                     try:
                         self.stages[name](self, ctx)
                     except faults.BankFault as e:
-                        # bank extraction died mid-stage: plans/thunks
-                        # built against the bank are shells — degrade, then
-                        # rebuild pack onward against the same
-                        # iteration-start snapshot and spawned streams (pure
-                        # functions → identical decisions, DESIGN.md §11)
-                        self._degrade_to_host(ctx.state,
-                                              "resident.bank.extract", e)
+                        # a bank extraction or a device sweep died
+                        # mid-stage: degrade, then rebuild pack onward
+                        # against the same iteration-start snapshot and
+                        # spawned streams (pure functions → identical
+                        # decisions, DESIGN.md §11)
+                        self._degrade_to_host(
+                            ctx.state, "resident.bank.extract"
+                            if self._run_ctx is not None
+                            else "resident.sweep", e)
                         self.stages["pack"](self, ctx)
                         if name == "merge_round":
                             self.stages["merge_round"](self, ctx)
@@ -447,14 +479,17 @@ class SummarizerEngine:
             transfer_prev = snap
             it_spans = SPANS.delta_since(spans_prev)
             spans_prev = SPANS.snapshot()
+            it_counts = COUNTS.delta_since(counts_prev)
+            counts_prev = COUNTS.snapshot()
             log.info(
                 "iter %3d: θ=%.3f groups=%d merges=%d roots=%d parts=%d "
-                "devices=%d host_sweeps=%d device_sweeps=%d chunks=%d "
-                "rounds=%d",
+                "devices=%d host_sweeps=%d device_sweeps=%d "
+                "sweep_devices=%d chunks=%d rounds=%d",
                 t, theta, len(ctx.groups), ctx.merges, state.alive.size,
                 self.partitions, self._devices,
                 it_spans.get("merge.host_sweep", {}).get("count", 0),
                 it_spans.get("merge.device_sweep", {}).get("count", 0),
+                it_counts.get("mesh.sweep_devices", 0),
                 it_spans.get("merge.chunk", {}).get("count", 0),
                 it_transfer["rounds"])
         self.stats["transfer"] = TRANSFER.delta_since(transfer0)

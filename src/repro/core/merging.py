@@ -26,6 +26,7 @@ makes every engine lossless by construction regardless of merge order.
 from __future__ import annotations
 
 import contextlib
+import functools
 import logging
 
 import numpy as np
@@ -1006,6 +1007,7 @@ def build_merge_work(
     rank_dispatch=None,
     resident_factory=None,
     shell_workspaces: bool = False,
+    sweep_devices=None,
 ):
     """Build record-mode workspaces for one iteration's candidate groups.
 
@@ -1023,14 +1025,19 @@ def build_merge_work(
     the batched intersection dispatch (mesh sharding);
     ``resident_factory(ws)`` overrides how ``backend="resident"`` builds
     its per-chunk `ResidentBitmapArena` (mesh placement, kernel forcing).
-    ``shell_workspaces`` (bank path, ISSUE 9) builds the batched chunks as
+    ``shell_workspaces`` (bank path) builds the batched chunks as
     shape-only shells — identical chunking and member layout, but the big
     CNT/bits/colsize tensors never materialize on host because the
     resident factory extracts them on device from the adjacency bank.
-    On that path an oversized group is a one-group shell too, and its
-    thunk sweeps the group's queue on device
-    (`ResidentBitmapArena.queue_sweep`); elsewhere it keeps its host
-    `GroupWorkspace` sweep.
+
+    An oversized group (over ``_BATCH_MAX_GROUP`` members) is swept on a
+    device in one program (`ResidentBitmapArena.queue_sweep`) with the
+    decisions of the host `_sweep_sequential`, from one of two arena
+    sources: on the bank path a one-group shell that the resident factory
+    extracts from the bank; with ``sweep_devices`` (a mesh run, which has
+    no bank; one device per group, aligned with ``groups``) a host-filled
+    one-group chunk uploaded whole to its group's device. Elsewhere it
+    keeps its host `GroupWorkspace` sweep.
     """
     groups = [np.asarray(g, dtype=np.int64) for g in groups]
     group_seeds = np.asarray(group_seeds, dtype=np.uint64)
@@ -1079,17 +1086,18 @@ def build_merge_work(
                                 height_bound=height_bound)
         return run
 
-    # on the bank path an oversized group is a one-group shell: its thunk
-    # extracts the group from the bank (``merge.extract``) and sweeps its
-    # queue on device in one program (``merge.device_sweep``), which returns
-    # the same ordered merges `_sweep_sequential` would record
-    def _device_thunk(ws, rng):
+    # an oversized group off the host is a one-group workspace: its thunk
+    # builds the group's arena with ``arena_of`` (``merge.extract``: the
+    # bank's extraction of a shell, or the upload of a dense chunk) and
+    # sweeps its queue on device in one program (``merge.device_sweep``),
+    # which returns the same ordered merges `_sweep_sequential` would record
+    def _device_thunk(ws, rng, arena_of):
         def run():
             from repro import faults
 
             with span("merge.thunk"):
                 with span("merge.extract"):
-                    arena = resident_factory(ws)
+                    arena = arena_of(ws)
                 with span("merge.device_sweep"):
                     perm = rng.permutation(int(ws.alive.sum()))
                     try:
@@ -1106,11 +1114,19 @@ def build_merge_work(
     buckets: dict = {}
     for i, grp in enumerate(groups):
         G = 1 << max(3, int(grp.size - 1).bit_length())
-        if grp.size > _BATCH_MAX_GROUP and shell_workspaces:
+        if grp.size > _BATCH_MAX_GROUP and (shell_workspaces
+                                            or sweep_devices is not None):
             ws, = BatchedGroupWorkspace.build_bucket(
                 state, [grp], G, plans=[plans[i]],
-                group_seeds=group_seeds[[i]], shell=True)
-            thunks.append(_device_thunk(ws, rng_of(i)))
+                group_seeds=group_seeds[[i]], shell=shell_workspaces)
+            if shell_workspaces:
+                arena_of = resident_factory
+            else:
+                from repro.core.resident import ResidentBitmapArena
+                arena_of = functools.partial(
+                    ResidentBitmapArena.from_workspace, top_j=top_j,
+                    device=sweep_devices[i])
+            thunks.append(_device_thunk(ws, rng_of(i), arena_of))
             continue
         if backend == "loop" or grp.size > _BATCH_MAX_GROUP:
             ws = GroupWorkspace(state, grp, plan=plans[i])

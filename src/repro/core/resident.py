@@ -33,10 +33,13 @@ count tensors on device — the host workspaces become shape-only shells and
 the device bank is authoritative within a run. The host materializes bank
 rows only for verification (`host_rows`, the `sync_rows`-style contract).
 
-A one-group arena extracted from the bank can instead sweep its whole
-queue in one device program (`queue_sweep`): that is how a candidate
-group over 128 members runs on the bank path, with the host sequential
-sweep's exact decisions.
+A one-group arena can instead sweep its whole queue in one device
+program (`queue_sweep`): that is how a candidate group over 128 members
+runs under ``backend="resident"``, with the host sequential sweep's exact
+decisions. On the bank path the arena is extracted from the bank
+(`from_bank`); on a mesh, which has no bank, it is a host-filled one-group
+chunk uploaded whole to one chip of the mesh (`from_workspace` with
+``device=``), and the groups are spread over the chips.
 
 `sync_rows` keeps the verification contract: tests pull selected rows back
 and assert the device fold is bit-identical to the host fold.
@@ -99,7 +102,7 @@ class ResidentBitmapArena:
     """Persistent device copy of one workspace chunk's packed bitmaps."""
 
     def __init__(self, bits_u32: np.ndarray, alive: np.ndarray, *,
-                 top_j: int = 16, mesh=None, use_kernel=None,
+                 top_j: int = 16, mesh=None, device=None, use_kernel=None,
                  interpret=None, counter=TRANSFER):
         jax = _jax()
         from repro.kernels.common import (default_interpret,
@@ -125,6 +128,8 @@ class ResidentBitmapArena:
             self.axes = ("data",)
             n_shards = 1
         self.mesh = mesh
+        # an unsharded arena lives on ``device`` (None: the default device)
+        self.device = None if mesh is not None else device
         # pad W to a pow2 and B to a pow2 multiple of the shard count so the
         # per-shape jit caches stay small; padded rows are dead and all-zero
         self.B = int(B)
@@ -152,15 +157,16 @@ class ResidentBitmapArena:
         self._counts = None    # v2 resident count state, or None (v1 mode)
 
     @classmethod
-    def from_workspace(cls, ws, *, top_j: int = 16, mesh=None,
+    def from_workspace(cls, ws, *, top_j: int = 16, mesh=None, device=None,
                        use_kernel=None, interpret=None, counter=TRANSFER,
                        with_counts: bool = True):
         """Upload a `BatchedGroupWorkspace` chunk's bitmaps (uint32 view of
         its uint64 words — bit positions follow the uint32 layout), and —
         unless ``with_counts=False`` — its exact integer count tensors, so
-        the whole sweep runs against resident state."""
+        the whole sweep runs against resident state. The arena is sharded
+        over ``mesh``, or else placed whole on ``device``."""
         bits = ws.bits.view(np.uint32)
-        arena = cls(bits, ws.alive, top_j=top_j, mesh=mesh,
+        arena = cls(bits, ws.alive, top_j=top_j, mesh=mesh, device=device,
                     use_kernel=use_kernel, interpret=interpret,
                     counter=counter)
         if with_counts:
@@ -231,6 +237,7 @@ class ResidentBitmapArena:
         arena.interpret = (default_interpret() if interpret is None
                            else bool(interpret))
         arena.mesh = None
+        arena.device = None
         arena.axes = ("data",)
         arena.B = B
         arena.Bp = pow2(B, floor=1)
@@ -264,6 +271,8 @@ class ResidentBitmapArena:
     # ------------------------------------------------------------- plumbing
     def _sharder(self, jax):
         if self.mesh is None:
+            if self.device is not None:
+                return lambda arr: jax.device_put(arr, self.device)
             import jax.numpy as jnp
             return jnp.asarray
         from jax.sharding import NamedSharding, PartitionSpec as P
@@ -278,8 +287,7 @@ class ResidentBitmapArena:
 
     def _replicate(self, arr):
         if self.mesh is None:
-            import jax.numpy as jnp
-            return jnp.asarray(arr)
+            return self._put(arr)
         import jax
         from jax.sharding import NamedSharding, PartitionSpec as P
         return jax.device_put(arr, NamedSharding(self.mesh, P()))
@@ -460,7 +468,7 @@ class ResidentBitmapArena:
                       height_bound=height_bound)
         args = (self._bits, self._alive, self._CNT, self._colsize,
                 self._memcol, self._s, self._selfc, self._nd, self._hgt,
-                self._cost, jnp.asarray(qpos), jnp.uint32(theta_p))
+                self._cost, self._put(qpos), jnp.uint32(theta_p))
         self.counter.add_h2d(qpos.nbytes + 4, phase="rank")
         m, pairs = jax.device_get(fn(*args))
         self.counter.add_d2h(pairs.nbytes + 4, phase="rank")

@@ -2,8 +2,8 @@
 
 `span(name)` times one stretch of the program at the boundary where its
 work happens: the engine's stages, set-up, the exchange's plan replay and
-bank advance, and inside the merge round the host sweeps of oversized
-groups, the batched chunks, the bank extraction, the device round trips and
+bank advance, and inside the merge round the host and device sweeps of
+oversized groups, the batched chunks, the bank extraction, the device round trips and
 the folds. Each span
 
 * opens ``jax.profiler.TraceAnnotation("slugger.<name>")`` when the program

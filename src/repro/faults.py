@@ -70,9 +70,10 @@ class InjectedFault(RuntimeError):
 
 
 class BankFault(RuntimeError):
-    """A failure on the resident adjacency-bank path, wrapped so the engine
-    can identify it and degrade to the host-rebuilt workspace path for the
-    rest of the run (DESIGN.md §11 degradation policy)."""
+    """A failure on a resident device path — a bank extraction, or a device
+    sweep of a group over 128 members — wrapped so the engine can identify
+    it and degrade to host workspaces and host sweeps for the rest of the
+    run (DESIGN.md §11 degradation policy)."""
 
 
 class FaultPlan:

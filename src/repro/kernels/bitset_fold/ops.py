@@ -11,7 +11,8 @@
   in place (the Pallas kernel additionally aliases input→output).
 
 `sweep_fn` compiles the one-group queue sweep (`ref.queue_sweep`) that
-the bank path runs for a candidate group over 128 members.
+the resident engine runs for a candidate group over 128 members: on the
+bank path, and on each chip of a mesh.
 
 Dispatch picks the Pallas kernels on TPU and their integer-exact jnp twins
 (`ref.py`) elsewhere (`kernels/common.default_use_kernel`); either path is
@@ -268,8 +269,10 @@ def sweep_fn(G: int, Rp: int, Wp: int, top_j: int, *, height_bound):
 
     ``(bits (1,G,Wp) u32, alive (1,G) i8, CNT (1,G,Rp) i32, colsize
     (1,Rp) i32, memcol/s/selfc/nd/hgt/cost (1,G) i32, qpos (G,) i32,
-    theta_p u32) -> (merges i32, pairs (G,2) i32)`` — the one-group arena
-    `ResidentBitmapArena.from_bank` extracts, swept to the end in one
+    theta_p u32) -> (merges i32, pairs (G,2) i32)`` — a one-group arena
+    (`ResidentBitmapArena.from_bank`'s extraction on the bank path, or
+    `from_workspace`'s upload to one chip of a mesh, where the program
+    runs on the chip its arena lives on), swept to the end in one
     dispatch. Nothing is donated: the loop updates its own copy in place.
     Group sizes bucket to powers of two and column widths to pow2, so a
     job compiles a handful of these.

@@ -128,19 +128,21 @@ def test_counts_snapshot_and_delta_under_threads():
 MESH_SPANS = ("mesh.shingle", "mesh.upload", "pack.fill")
 
 
-def _hub_graph() -> Graph:
-    """Caveman cliques plus one hub with degree-one leaves: the leaves
-    share a shingle, so one candidate group is over 128 members and is
-    swept on the device from the bank; the cliques fill batched chunks."""
+def _hub_graph(hubs: int = 1) -> Graph:
+    """Caveman cliques plus ``hubs`` hubs, each with its own degree-one
+    leaves: one hub's leaves share a shingle, so each hub makes one
+    candidate group over 128 members, swept on a device; the cliques fill
+    batched chunks."""
     g0 = GG.caveman(30, 6, 0.05, seed=3)
     src = np.repeat(np.arange(g0.n), np.diff(g0.indptr))
     keep = src < g0.indices
-    hub = g0.n
-    leaves = np.arange(hub + 1, hub + 1 + HUB_LEAVES)
-    edges = np.concatenate([
-        np.stack([src[keep], g0.indices[keep]], axis=1),
-        np.stack([np.full(HUB_LEAVES, hub), leaves], axis=1)])
-    return Graph.from_edges(hub + 1 + HUB_LEAVES, edges.astype(np.int64))
+    parts = [np.stack([src[keep], g0.indices[keep]], axis=1)]
+    n = g0.n
+    for _ in range(hubs):
+        leaves = np.arange(n + 1, n + 1 + HUB_LEAVES)
+        parts.append(np.stack([np.full(HUB_LEAVES, n), leaves], axis=1))
+        n += 1 + HUB_LEAVES
+    return Graph.from_edges(n, np.concatenate(parts).astype(np.int64))
 
 
 def _job(g, stages=None):
@@ -264,18 +266,29 @@ MESH_JOB = textwrap.dedent("""
     from jax.sharding import Mesh
     sys.path.insert(0, sys.argv[1])
     import test_spans as T
+    from repro import faults
     from repro.core.engine import SummarizerEngine
+    from repro.core.merging import _BATCH_MAX_GROUP
 
-    g = T._hub_graph()
+    g = T._hub_graph(hubs=2)
     mesh = Mesh(np.array(jax.devices()), ("data",))
+    oversized = []
+
+    def stage_group(engine, ctx):
+        SummarizerEngine.stage_group(engine, ctx)
+        oversized.append(sum(len(grp) > _BATCH_MAX_GROUP
+                             for grp in ctx.groups))
 
     def job():
+        oversized.clear()
         eng = SummarizerEngine(backend="resident", partitions=4, T=3, seed=2,
-                               workers=4, mesh=mesh)
+                               workers=4, mesh=mesh,
+                               stages={"group": stage_group})
         s = eng.run(g)
         return eng, s, np.asarray(s.parent), np.asarray(s.edges)
 
     eng, summary, parent, edges = job()
+    n_over = sum(oversized)
     logdir = tempfile.mkdtemp()
     jax.profiler.start_trace(logdir)
     try:
@@ -285,10 +298,19 @@ MESH_JOB = textwrap.dedent("""
     one = SummarizerEngine(backend="resident", partitions=4, T=3, seed=2,
                            workers=4, mesh=Mesh(np.array(jax.devices()[:1]),
                                                 ("data",))).run(g)
+    with faults.inject("kernel.bitset_fold.sweep"):
+        f_eng, f_summary, f_parent, f_edges = job()
     st = {k: v for k, v in eng.stats.items()
           if isinstance(v, (int, float))}
     print("RESULT " + json.dumps({
         "stats": st, "span_counts": eng.stats["span_counts"],
+        "oversized": n_over,
+        "fault": {"degradations": f_eng.stats["degradations"],
+                  "host_sweeps": f_eng.stats["span_counts"].get(
+                      "merge.host_sweep", 0),
+                  "same": bool(np.array_equal(parent, f_parent)
+                               and np.array_equal(edges, f_edges)),
+                  "lossless": bool(f_summary.validate_lossless(g))},
         "traced_same": bool(np.array_equal(parent, t_parent)
                             and np.array_equal(edges, t_edges)),
         "one_device_same": bool(np.array_equal(parent, one.parent)
@@ -326,10 +348,19 @@ def test_mesh_job_reads_its_spans_and_tallies(mesh_job):
     assert 0 < rows <= padded and padded % 4 == 0
     # every arena's bits hold a shard on each of the four devices
     assert st["mesh.shard_devices"] == 4 * arenas
-    # no bank on a mesh: the oversized group is swept on the host
-    assert counts["merge.host_sweep"] >= 1
-    assert "merge.device_sweep" not in counts
+    # no bank on a mesh: each oversized group is swept on one device of
+    # the mesh from a host-filled arena, and the sweeps use more than one
+    assert "merge.host_sweep" not in counts
+    assert counts["merge.device_sweep"] == mesh_job["oversized"] >= 2
+    assert st["mesh.sweep_devices"] > 1
     assert counts["mesh.upload"] == 2 * arenas
+
+
+def test_mesh_device_sweep_fault_degrades_to_host_sweep(mesh_job):
+    fault = mesh_job["fault"]
+    assert fault["degradations"] >= 1
+    assert fault["host_sweeps"] >= 1
+    assert fault["same"] and fault["lossless"]
 
 
 def test_mesh_summary_identical_under_profiler_and_to_one_device(mesh_job):

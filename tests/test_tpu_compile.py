@@ -9,7 +9,8 @@ G are compiled too — and W, the packed-bitmap word count, is a power of
 two. The serving kernel sees the fixed query-slot batch (or a short one)
 and power-of-two interval/probe widths. The queue sweep of a group over
 128 members is a jitted loop with no Pallas kernel: it is compiled at the
-two oversized group buckets and at a narrow and a hub-wide column width.
+two oversized group buckets and at a narrow and a hub-wide column width,
+and on each chip of the host, where the mesh path places it.
 
 The mesh path shards the arenas' group axis over all four chips of the
 v5e:2x2 host: the ranking (`topj_fn`) and the count-carrying fold are
@@ -185,6 +186,24 @@ def test_queue_sweep_compiles(one_chip, G, Rp):
         *[_spec(one_chip, (1, G), i32) for _ in range(6)],
         _spec(one_chip, (G,), i32), _spec(one_chip, (), jnp.uint32)).compile()
     assert " while(" in compiled.as_text()
+
+
+@pytest.mark.parametrize("chip", (1, 2, 3))
+def test_queue_sweep_compiles_on_each_chip_of_the_mesh(topo, chip):
+    """On a mesh each oversized group's arena is placed whole on one of the
+    host's chips, and its sweep program is compiled for that chip."""
+    G, Rp = 512, 16384
+    Wp = Rp // 32
+    on = SingleDeviceSharding(topo.devices[chip])
+    i32 = jnp.int32
+    compiled = sweep_fn(G, Rp, Wp, 16, height_bound=2).jitted.lower(
+        _spec(on, (1, G, Wp), jnp.uint32), _spec(on, (1, G), jnp.int8),
+        _spec(on, (1, G, Rp), i32), _spec(on, (1, Rp), i32),
+        *[_spec(on, (1, G), i32) for _ in range(6)],
+        _spec(on, (G,), i32), _spec(on, (), jnp.uint32)).compile()
+    assert " while(" in compiled.as_text()
+    assert {d for sh in compiled.input_shardings[0]
+            for d in sh.device_set} == {topo.devices[chip]}
 
 
 @pytest.mark.parametrize("B,E,P", [
