@@ -36,7 +36,7 @@ from __future__ import annotations
 
 import numpy as np
 
-from repro.core.summary_ir import SummaryIR, group_pairs
+from repro.core.summary_ir import SummaryIR, canon_edges, group_pairs
 
 
 def forest_is_binary(ir: SummaryIR) -> bool:
@@ -69,6 +69,8 @@ def _state_poss(ir: SummaryIR, sx: np.ndarray, sy: np.ndarray) -> np.ndarray:
 
 def _dedup_states(sx_e, sy_e):
     """Edge-level (sx, sy) pairs -> unique state table + per-edge index."""
+    # unstable: an edge's state is its pair's group, whatever the order of
+    # the members within the group
     order, starts = group_pairs(sx_e, sy_e)
     nstates = starts.shape[0]
     st_sorted = np.zeros(sx_e.shape[0], dtype=np.int64)
@@ -254,10 +256,6 @@ def encode_forest(ir: SummaryIR, u: np.ndarray, v: np.ndarray):
 
     if not out_x:
         return cost, empty
-    ex = np.concatenate(out_x)
-    ey = np.concatenate(out_y)
-    es = np.concatenate(out_s)
-    lo, hi = np.minimum(ex, ey), np.maximum(ex, ey)
-    edges = np.stack([lo, hi, es], axis=1)
-    order = np.lexsort((edges[:, 2], edges[:, 1], edges[:, 0]))
-    return cost, edges[order]
+    edges = np.stack([np.concatenate(out_x), np.concatenate(out_y),
+                      np.concatenate(out_s)], axis=1)
+    return cost, canon_edges(edges)

@@ -89,6 +89,11 @@ SPAN_STATS = {
 COUNT_STATS = ("mesh.arenas", "mesh.rows", "mesh.rows_padded",
                "mesh.shard_devices", "mesh.sweep_devices")
 
+# the same, over emission and pruning (`run`): the pair lists they ordered
+# or searched by the packed int64 key, and those that fell back to the
+# multi-key lexsort (`summary_ir.pair_key`)
+POST_COUNT_STATS = ("post.sorts", "post.sort_fallbacks")
+
 
 class IterationContext:
     """Mutable scratch shared by one iteration's stages."""
@@ -506,6 +511,7 @@ class SummarizerEngine:
                                       resume=resume,
                                       checkpoint_every=checkpoint_every)
         owner = pg.owner if self.partitions > 1 else None
+        counts0 = COUNTS.snapshot()
         with span("emit") as sp:
             summary = _emit_encoding(state, backend=self.backend, owner=owner)
         self.stats["emit"] = sp.wall
@@ -514,4 +520,12 @@ class SummarizerEngine:
                 summary = prune(summary, steps=self.prune_steps,
                                 partition_map=owner)
             self.stats["prune"] = sp.wall
+        counts = COUNTS.delta_since(counts0)
+        for key in POST_COUNT_STATS:
+            self.stats[key] = float(counts.get(key, 0))
+        log.info("post-merge: emit %.3fs prune %.3fs edges=%d sorts=%d "
+                 "sort_fallbacks=%d", self.stats["emit"],
+                 self.stats.get("prune", 0.0),
+                 summary.edges.shape[0], counts.get("post.sorts", 0),
+                 counts.get("post.sort_fallbacks", 0))
         return summary

@@ -37,14 +37,19 @@ import numpy as np
 
 from repro.core.summary import Summary
 from repro.core.summary_ir import (SummaryIR, canon_edges, group_pairs,
-                                   segmented_indices)
+                                   pair_key, pair_key_span, segmented_indices)
 
 
 def _aggregate_pairs(ex, ey, ec):
-    """Normalize (x, y) pairs, sum multiplicities, drop zero nets."""
+    """Normalize (x, y) pairs, sum multiplicities, drop zero nets. Rows come
+    out one per pair in (lo, hi) order: the canonical hand-off that every
+    later pass (lookups, `to_summary`) takes as sorted."""
     lo = np.minimum(ex, ey)
     hi = np.maximum(ex, ey)
-    order, starts = group_pairs(lo, hi)
+    # stable: every list this gets is sorted runs (a sorted hand-off plus the
+    # rows a pass appends), which a stable sort merges in near-linear time;
+    # the members of one pair are summed, so their order is not observed
+    order, starts = group_pairs(lo, hi, stable=True)
     if lo.size == 0:
         z = np.zeros(0, dtype=np.int64)
         return z, z.copy(), z.copy()
@@ -54,16 +59,37 @@ def _aggregate_pairs(ex, ey, ec):
     return lo[starts][keep], hi[starts][keep], sums[keep]
 
 
-def _pair_lookup(bex, bey, bec, qx, qy):
+def _pair_lookup(bex, bey, bec, qx, qy, span=None, bkey=None, qkey=None):
     """Multiplicity of each query pair in the base pair list (0 if absent).
 
-    Both inputs are pair lists; the base is unique per pair. One shared
-    lexsort aligns queries next to their base row — no combined integer key,
-    so arbitrarily large ids cannot overflow (see summary_ir.group_pairs).
+    Where the ids pack (`summary_ir.pair_key`, under ``span``, by default
+    the lists' own), each query's key is found by binary search in the
+    base's sorted keys. A base that is already in (lo, hi) order with one
+    row per pair, as every pass hands it on, is not sorted again; a caller
+    that looks up one list several times passes its key as ``bkey`` or
+    ``qkey``, made under the same ``span``. Past the key's bound one shared
+    lexsort aligns queries next to their base row, with no combined integer
+    key, so arbitrarily large ids cannot overflow.
     """
     nq = qx.shape[0]
     if nq == 0 or bex.shape[0] == 0:
         return np.zeros(nq, dtype=np.int64)
+    if span is None:
+        span = pair_key_span(bex, bey, qx, qy)
+    if qkey is None:
+        qkey = pair_key(span, qx, qy)
+    if qkey is not None:
+        if bkey is None:
+            bkey = pair_key(span, bex, bey)
+        bval = bec
+        if not bool(np.all(bkey[1:] > bkey[:-1])):
+            # unsorted or repeated base pairs: sum each pair's rows
+            order, starts = group_pairs(bex, bey)
+            bkey = bkey[order][starts]
+            bval = np.add.reduceat(bec[order], starts)
+        j = np.searchsorted(bkey, qkey)
+        j[j == bkey.shape[0]] = 0
+        return np.where(bkey[j] == qkey, bval[j], 0)
     allx = np.concatenate([bex, qx])
     ally = np.concatenate([bey, qy])
     isq = np.zeros(allx.shape[0], dtype=np.int64)
@@ -90,6 +116,8 @@ class _IRWork:
         self.n = s.n_leaves
         self.parent = np.asarray(s.parent, dtype=np.int64).copy()
         edges = np.asarray(s.edges, dtype=np.int64).reshape(-1, 3)
+        # emission hands its edges on in canonical order, so grouping them
+        # is one run-length pass, with no sort
         self.ex, self.ey, self.ec = _aggregate_pairs(
             edges[:, 0], edges[:, 1], edges[:, 2])
 
@@ -151,9 +179,11 @@ class _IRWork:
         cands = np.flatnonzero(cand_mask)
         if cands.size == 0:
             return cands, cands, cands, cands
-        order = np.argsort(ends, kind="stable")
-        pos = np.searchsorted(ends[order], cands)
-        eid = eids[order][pos]
+        # a candidate has exactly one incidence entry: scatter it, no sort
+        at = np.full(cap, -1, dtype=np.int64)
+        hit = cand_mask[ends]
+        at[ends[hit]] = eids[hit]
+        eid = at[cands]
         ok = np.abs(self.ec[eid]) == 1
         cands, eid = cands[ok], eid[ok]
         cand_mask = np.zeros(cap, dtype=bool)
@@ -169,18 +199,16 @@ class _IRWork:
             cands, eid, other, sg = self._step2_candidates()
             if cands.size == 0:
                 return removed
-            # push each candidate's single edge down to its children
-            nk = self._nkids()
-            haspar = self._alive() & (self.parent >= 0)
-            kids = np.flatnonzero(haspar)
-            kids = kids[np.argsort(self.parent[kids], kind="stable")]
-            kptr = np.zeros(self._cap() + 1, dtype=np.int64)
-            np.cumsum(nk, out=kptr[1:])
-            lens = nk[cands]
-            idx = segmented_indices(kptr[cands], lens)
-            new_x = kids[idx]
-            new_y = np.repeat(other, lens)
-            new_c = np.repeat(sg, lens)
+            # push each candidate's single edge down to its children; the
+            # rows go to _aggregate_pairs, which orders them, so the kids
+            # are taken in id order
+            slot = np.full(self._cap(), -1, dtype=np.int64)
+            slot[cands] = np.arange(cands.size)
+            kids = np.flatnonzero(self._alive() & (self.parent >= 0))
+            own = slot[self.parent[kids]]
+            new_x, own = kids[own >= 0], own[own >= 0]
+            new_y = other[own]
+            new_c = sg[own]
             keep = np.ones(self.ex.shape[0], dtype=bool)
             keep[eid] = False
             self.ex, self.ey, self.ec = _aggregate_pairs(
@@ -194,7 +222,7 @@ class _IRWork:
             removed += cands.size
 
     # ---- step 3 (benefit-tested splice of any non-leaf supernode) ----------
-    def _step3_bulk(self, ir, cands, nk, sizes, bex, bey, bec, delta):
+    def _step3_bulk(self, ir, cands, nk, sizes, bex, bey, bec, bkey, delta):
         """Bulk feasibility/plan/delta pass for one candidate subset.
 
         Emits the subset's plan rows (plo, phi, ps, pc) and accumulates each
@@ -246,7 +274,8 @@ class _IRWork:
         ps = np.concatenate([ps1] + ps2)
         pc = np.concatenate([pc1] + pc2)
         plo, phi = np.minimum(pu, pv), np.maximum(pu, pv)
-        cur = _pair_lookup(bex, bey, bec, plo, phi)
+        cur = _pair_lookup(bex, bey, bec, plo, phi, span=self._cap(),
+                           bkey=bkey)
         contrib = np.where(cur == -ps, -1, 1)
         np.add.at(delta, pc, contrib)
         return plo, phi, ps, pc
@@ -261,9 +290,13 @@ class _IRWork:
         if cands.size == 0:
             return 0
         # deterministic bottom-up order: deepest first, then ascending id
-        cands = cands[np.lexsort((cands, -ir.depth[cands]))]
+        # (stable over the ascending cands)
+        cands = cands[np.argsort(-ir.depth[cands], kind="stable")]
         sizes = ir.size(ids)
         bex, bey, bec = self.ex, self.ey, self.ec
+        # the base list is in (lo, hi) order, one row per pair
+        # (_aggregate_pairs): key it once for every lookup below
+        bkey = pair_key(cap, bex, bey)
         ir.build_incidence(np.stack([bex, bey, bec], axis=1))
 
         # -- bulk pass: feasibility, plans, deltas against the entry state --
@@ -289,7 +322,7 @@ class _IRWork:
         plo_b, phi_b, ps_b, pc_b = [], [], [], []
         for csub in buckets:
             plo_c, phi_c, ps_c, pc_c = self._step3_bulk(
-                ir, csub, nk, sizes, bex, bey, bec, delta)
+                ir, csub, nk, sizes, bex, bey, bec, bkey, delta)
             plo_b.append(plo_c)
             phi_b.append(phi_c)
             ps_b.append(ps_c)
@@ -312,15 +345,14 @@ class _IRWork:
         kids_mut: dict = {}     # node -> current child list (if changed)
         dirty = np.zeros(cap, dtype=bool)
         parent = self.parent
-        b_order = np.argsort(bex, kind="stable")
-        sbex, sbey = bex[b_order], bey[b_order]
 
         def base_mult(x, y):
-            lo = np.searchsorted(sbex, x, side="left")
-            hi = np.searchsorted(sbex, x, side="right")
-            j = lo + np.searchsorted(sbey[lo:hi], y)
-            if j < hi and sbey[j] == y:
-                return int(bec[b_order[j]])
+            # the base list is sorted by (lo, hi): search it in place
+            lo = np.searchsorted(bex, x, side="left")
+            hi = np.searchsorted(bex, x, side="right")
+            j = lo + np.searchsorted(bey[lo:hi], y)
+            if j < hi and bey[j] == y:
+                return int(bec[j])
             return 0
 
         def mult(x, y):
@@ -436,7 +468,8 @@ class _IRWork:
             ovy = np.array([k[1] for k, _ in ov], dtype=np.int64)
             ovc = np.array([v for _, v in ov], dtype=np.int64)
             # overlay values are absolute: drop overlaid base rows, then add
-            overlaid = _pair_lookup(ovx, ovy, np.ones_like(ovc), bex, bey) > 0
+            overlaid = _pair_lookup(ovx, ovy, np.ones_like(ovc), bex, bey,
+                                    span=cap, qkey=bkey) > 0
             nz = ovc != 0
             self.ex, self.ey, self.ec = _aggregate_pairs(
                 np.concatenate([bex[~overlaid], ovx[nz]]),
@@ -447,14 +480,16 @@ class _IRWork:
 
     # ---- export ------------------------------------------------------------
     def to_summary(self) -> Summary:
+        # the pairs are in (lo, hi) order, one row each with one sign
+        # (_aggregate_pairs), so expanding the multiplicities in place is
+        # the canonical (lo, hi, sign) order: no sort
         reps = np.abs(self.ec)
         rows = np.stack([
             np.repeat(self.ex, reps),
             np.repeat(self.ey, reps),
             np.repeat(np.sign(self.ec), reps),
         ], axis=1)
-        return Summary(n_leaves=self.n, parent=self.parent,
-                       edges=canon_edges(rows))
+        return Summary(n_leaves=self.n, parent=self.parent, edges=rows)
 
 
 class _Work:

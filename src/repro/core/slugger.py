@@ -34,7 +34,7 @@ import numpy as np
 from repro.core import encode_dp
 from repro.core.encode_batched import encode_forest, forest_is_binary
 from repro.core.summary import Summary
-from repro.core.summary_ir import SummaryIR, canon_edges
+from repro.core.summary_ir import SummaryIR, canon_edges, group_pairs
 from repro.graphs.csr import Graph
 
 
@@ -294,13 +294,11 @@ def _emit_encoding_reference(state: SluggerState) -> Summary:
             u = np.where(swap, el[:, 1], el[:, 0])
             v = np.where(swap, el[:, 0], el[:, 1])
             ka, kb = np.minimum(ra, rb), np.maximum(ra, rb)
-            order = np.lexsort((kb, ka))
+            # root-pair groups; stable, so each pair's DP reads its edges in
+            # edge-list order (summary_ir.group_pairs)
+            order, starts = group_pairs(ka, kb, stable=True)
             u, v, ka, kb = u[order], v[order], ka[order], kb[order]
-            # root-pair groups split on component diffs — unlike the previous
-            # ka * (max(kb)+1) + kb keying this cannot overflow int64 however
-            # large the supernode ids grow (see summary_ir.group_pairs).
-            head = (np.diff(ka) != 0) | (np.diff(kb) != 0)
-            bounds = np.concatenate([[0], np.flatnonzero(head) + 1, [ka.shape[0]]])
+            bounds = np.concatenate([starts, [ka.shape[0]]])
             for i in range(bounds.shape[0] - 1):
                 s, e = bounds[i], bounds[i + 1]
                 A, B = int(ka[s]), int(kb[s])

@@ -24,6 +24,8 @@ from __future__ import annotations
 
 import numpy as np
 
+from repro.core.spans import COUNTS
+
 
 def segmented_indices(starts: np.ndarray, lens: np.ndarray) -> np.ndarray:
     """Flat gather indices for CSR slices: ``concat(arange(s, s+l))``.
@@ -39,6 +41,55 @@ def segmented_indices(starts: np.ndarray, lens: np.ndarray) -> np.ndarray:
     )
 
 
+# A (lo, hi[, sign]) pair list sorts on one packed int64 key,
+# ``(lo * span + hi) * 2 + (sign > 0)`` with ``span`` above every id: its
+# order is the lexicographic (lo, hi, sign) order, and one np.sort or
+# np.argsort of it replaces a multi-key np.lexsort (about ten times the
+# cost). It holds while span < 2**31, where lo * span + hi < 2**62 and the
+# doubled key cannot overflow; past that bound, or with a negative id or a
+# sign other than ±1, the list falls back to the lexsort. `spans.COUNTS`
+# tallies each list keyed (``post.sorts``) and each fallback
+# (``post.sort_fallbacks``).
+PAIR_KEY_SPAN_LIMIT = 1 << 31
+
+
+def pair_key_span(*ids: np.ndarray):
+    """The packed key's ``span`` for these id arrays: max id + 1 (1 when
+    all are empty), or None when an id is negative or the span reaches
+    `PAIR_KEY_SPAN_LIMIT`."""
+    span = 1
+    for a in ids:
+        if a.size:
+            if a.min() < 0:
+                return None
+            span = max(span, int(a.max()) + 1)
+    return span if span < PAIR_KEY_SPAN_LIMIT else None
+
+
+def pair_key(span, lo: np.ndarray, hi: np.ndarray, sign=None):
+    """The packed key of each (lo, hi[, sign]) row, or None where the rows
+    do not pack: ``span`` is None or past the bound, or a sign is not ±1.
+    Every id must lie in [0, span). Counts one ``post.sorts`` or one
+    ``post.sort_fallbacks``."""
+    if (span is not None and span < PAIR_KEY_SPAN_LIMIT
+            and (sign is None or bool(np.all(np.abs(sign) == 1)))):
+        COUNTS.add("post.sorts", 1)
+        key = np.asarray(lo, dtype=np.int64) * span
+        key += hi
+        key *= 2
+        if sign is not None:
+            key += sign > 0
+        return key
+    COUNTS.add("post.sort_fallbacks", 1)
+    return None
+
+
+def unpack_pair_key(key: np.ndarray, span: int):
+    """Inverse of `pair_key`: ``(lo, hi, sign)``, sign ±1 from the low bit."""
+    lo, hi = np.divmod(key >> 1, span)
+    return lo, hi, (key & 1) * 2 - 1
+
+
 def canon_edges(arr: np.ndarray) -> np.ndarray:
     """Canonical (lo, hi, sign) lexicographic row order. Edge row order is
     not semantically meaningful, so every emitter/pruner exports this order
@@ -48,31 +99,48 @@ def canon_edges(arr: np.ndarray) -> np.ndarray:
         return np.zeros((0, 3), dtype=np.int64)
     lo = np.minimum(arr[:, 0], arr[:, 1])
     hi = np.maximum(arr[:, 0], arr[:, 1])
-    arr = np.stack([lo, hi, arr[:, 2]], axis=1)
-    return arr[np.lexsort((arr[:, 2], arr[:, 1], arr[:, 0]))]
+    span = pair_key_span(lo, hi)
+    key = pair_key(span, lo, hi, arr[:, 2])
+    if key is None:
+        arr = np.stack([lo, hi, arr[:, 2]], axis=1)
+        return arr[np.lexsort((arr[:, 2], arr[:, 1], arr[:, 0]))]
+    # rows with equal keys are equal rows, so the sorted keys (unstable
+    # sort) are the whole canonical array
+    return np.stack(unpack_pair_key(np.sort(key), span), axis=1)
 
 
-def group_pairs(a: np.ndarray, b: np.ndarray):
-    """Group index pairs without forming a combined integer key.
+def group_pairs(a: np.ndarray, b: np.ndarray, stable: bool = False):
+    """Group index pairs by (a, b).
 
     Returns ``(order, starts)``: ``order`` sorts the pairs lexicographically
     by (a, b) and ``starts`` marks the first element of each distinct pair in
-    the sorted view (append ``len`` for bounds). Unlike the
-    ``a * (max(b)+1) + b`` keying this cannot overflow int64 for any id range
-    — the same reason ``SluggerState.gather_rows`` keys with a bounded
-    multiplier; here we drop the multiplier entirely and split on the sorted
-    component diffs instead.
+    the sorted view (append ``len`` for bounds). The order of the members of
+    one pair is unspecified unless ``stable`` keeps it the input order.
+
+    Pairs that pack sort their `pair_key` (an already sorted list is not
+    sorted again). Ids past the key's bound take a 2-key np.lexsort, which
+    cannot overflow int64 for any id range: the groups split on the sorted
+    component diffs, with no combined key.
     """
     a = np.asarray(a, dtype=np.int64)
     b = np.asarray(b, dtype=np.int64)
-    order = np.lexsort((b, a))
     if a.size == 0:
-        return order, np.zeros(0, dtype=np.int64)
-    sa, sb = a[order], b[order]
+        return np.zeros(0, dtype=np.int64), np.zeros(0, dtype=np.int64)
     head = np.empty(a.size, dtype=bool)
     head[0] = True
-    np.not_equal(sa[1:], sa[:-1], out=head[1:])
-    head[1:] |= sb[1:] != sb[:-1]
+    key = pair_key(pair_key_span(a, b), a, b)
+    if key is None:
+        order = np.lexsort((b, a))
+        sa, sb = a[order], b[order]
+        np.not_equal(sa[1:], sa[:-1], out=head[1:])
+        head[1:] |= sb[1:] != sb[:-1]
+        return order, np.flatnonzero(head)
+    if bool(np.all(key[1:] >= key[:-1])):
+        order = np.arange(a.size, dtype=np.int64)
+    else:
+        order = np.argsort(key, kind="stable" if stable else None)
+        key = key[order]
+    np.not_equal(key[1:], key[:-1], out=head[1:])
     return order, np.flatnonzero(head)
 
 
@@ -209,7 +277,14 @@ class SummaryIR:
             np.arange(k, dtype=np.int64),
             np.flatnonzero(nonloop),
         ])
-        order = np.argsort(ends, kind="stable")
+        # stable: each node's incidence lists its edges in edge order. Where
+        # it fits, one plain sort of (end, index) packed into an int64 gives
+        # that order without a stable argsort.
+        n_e = ends.size
+        if self.n_ids * n_e <= np.iinfo(np.int64).max:
+            order = np.sort(ends * n_e + np.arange(n_e, dtype=np.int64)) % n_e
+        else:
+            order = np.argsort(ends, kind="stable")
         self.inc_eid = eids[order]
         counts = np.bincount(ends, minlength=self.n_ids)
         self.inc_ptr = np.zeros(self.n_ids + 1, dtype=np.int64)

@@ -84,6 +84,28 @@ def test_equivalence_on_random_graphs():
         assert a.decompress() == g
 
 
+@pytest.mark.parametrize("partitions", (1, 4))
+def test_kronecker_emission_and_pruning_match_references(partitions):
+    """A scale-12 Kronecker graph's forest, emitted (owner buckets when
+    partitioned) and pruned (step-3 buckets) by the numpy path: bit-identical
+    to the recursive emitter and to the dict pruner."""
+    from repro.core.engine import SummarizerEngine
+
+    g = GG.rmat(12, edge_factor=16, seed=12)
+    state, pg = SummarizerEngine(backend="numpy", partitions=partitions,
+                                 T=2, seed=3).merge_forest(g)
+    owner = pg.owner if partitions > 1 else None
+    ref = _emit_encoding_reference(state)
+    new = _emit_encoding(state, backend="numpy", owner=owner)
+    assert np.array_equal(ref.parent, new.parent)
+    assert np.array_equal(ref.edges, new.edges)
+    a = prune(new, partition_map=owner)
+    b = prune(new, impl="dict")
+    assert np.array_equal(a.parent, b.parent)
+    assert np.array_equal(a.edges, b.edges)
+    assert a.validate_lossless(g)
+
+
 def test_nonbinary_forest_rejected_by_batched_emitter():
     # 3 leaves under one parent: encode_forest must refuse (the emission
     # wrapper then falls back to the recursive reference)
